@@ -23,7 +23,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import emit
-from repro import compat
 from repro.core import ForestParams, impurity, prediction, tree
 
 COLL_PRIMS = ("psum", "all_gather", "all_to_all", "ppermute",
@@ -65,14 +64,14 @@ def run() -> dict:
     m, depth, n_est, n, f = 4, 5, 3, 64, 12
     fp = f // m
     p = ForestParams(n_estimators=n_est, max_depth=depth, n_bins=8)
-    mesh = compat.abstract_mesh((m,), ("parties",))
+    mesh = jax.sharding.AbstractMesh((m,), ("parties",))
 
     # ---- training schedule (one tree: lax.map body traced once) ----------
     def fit_local(xb, gid, sel, w, ys):
         out = tree.build_tree(xb[0], gid[0], sel, w, ys, p)
         return jax.tree.map(lambda a: a[None], out)
 
-    fit = compat.shard_map(
+    fit = jax.shard_map(
         fit_local, mesh=mesh,
         in_specs=(P("parties"), P("parties"), P(), P(), P()),
         out_specs=P("parties"), check_vma=False)
@@ -104,10 +103,10 @@ def run() -> dict:
     tree_specs = jax.tree.map(lambda _: P("parties"), stacked,
                               is_leaf=lambda x: hasattr(x, "shape"))
     xbt = jnp.zeros((m, 32, fp), jnp.uint8)
-    c_one = _count_collectives(jax.make_jaxpr(compat.shard_map(
+    c_one = _count_collectives(jax.make_jaxpr(jax.shard_map(
         pred_one_local, mesh=mesh, in_specs=(tree_specs, P("parties")),
         out_specs=P("parties"), check_vma=False))(stacked, xbt))
-    c_cls = _count_collectives(jax.make_jaxpr(compat.shard_map(
+    c_cls = _count_collectives(jax.make_jaxpr(jax.shard_map(
         pred_cls_local, mesh=mesh, in_specs=(tree_specs, P("parties")),
         out_specs=P("parties"), check_vma=False))(stacked, xbt))
 

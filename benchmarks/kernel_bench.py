@@ -1,6 +1,6 @@
 """Kernel micro-benchmarks: histogram impls (the FF hot spot) + attention.
 
-On this CPU host the Pallas kernels run in interpret mode (orders of
+The Pallas histogram runs here through ``pallas_interpret`` (orders of
 magnitude slower than compiled — the number reported here is a correctness
 path, not a TPU projection).  The scatter impl is the CPU production path;
 the table is mainly here so regressions in the hot loop show up.
@@ -30,7 +30,7 @@ def run() -> list[dict]:
     # pallas interpret mode on a reduced shape (correctness path, not a TPU
     # projection — interpret executes the kernel body in Python)
     xs, ss, st = xb[:512, :8], seg[:512], stats[:512]
-    t = timeit(lambda: ops.histogram(xs, ss, st, l, b, "pallas")
+    t = timeit(lambda: ops.histogram(xs, ss, st, l, b, "pallas_interpret")
                .block_until_ready(), repeat=1)
     rows.append({"impl": "pallas_interpret", "seconds": t})
     emit("kernel/histogram_pallas_interpret", t, "reduced_shape=512x8")

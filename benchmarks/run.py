@@ -22,6 +22,8 @@ def main(argv=None) -> None:
     ap.add_argument("--json-out", default=None, metavar="FILE",
                     help="also dump all bench records as a JSON summary")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     t0 = time.time()

@@ -8,11 +8,13 @@ idiom the federation tests use), times both, and asserts the sharded build
 is bit-identical to the single-device vmap simulation — so the number it
 reports is the real protocol, not a shape-polymorphic proxy.
 
-The mesh is launched in a subprocess so the forced device count cannot leak
-into the rest of the bench harness.  On a single physical core the forced
-devices time-slice, so wall-clock here anchors correctness + overhead of the
-sharded path; on a genuinely multi-core/multi-chip host the same harness
-measures real scaling.  REPRO_BENCH_FAST=1 shrinks the mesh to (2, 2) and
+The mesh is launched in a CPU-only subprocess (``JAX_PLATFORMS=cpu``) so
+the forced device count cannot leak into the rest of the bench harness, and
+the child never contends for an accelerator its parent holds.  On a single
+physical core the forced devices time-slice, so wall-clock here anchors
+correctness + overhead of the sharded path; on a multi-core host the same
+harness measures host-device scaling (chips are measured by
+``chip_smoke.py --four-chips``).  REPRO_BENCH_FAST=1 shrinks the mesh to (2, 2) and
 the training set.
 """
 from __future__ import annotations
@@ -28,6 +30,7 @@ import os, time
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
 import numpy as np
 import jax, jax.numpy as jnp
+from repro import compat
 from repro.core import ForestParams, fit_federated_forest
 from repro.data import make_classification
 
@@ -37,7 +40,7 @@ p = ForestParams(n_estimators={trees}, max_depth=6, n_bins=16, seed=0)
 x, y = make_classification(n, f, 2, seed=0)
 
 from repro.federation import Federation
-mesh = jax.make_mesh((trees_ax, parties), ("trees", "parties"))
+mesh = compat.make_mesh((trees_ax, parties), ("trees", "parties"))
 fed = Federation(parties=parties, substrate="sharded", mesh=mesh,
                  hist_impl="scatter", n_bins=p.n_bins)
 fed.ingest(x, y)
@@ -69,7 +72,9 @@ def run() -> list[dict]:
     cfg = dict(devices=trees_ax * parties, trees_ax=trees_ax,
                parties=parties, trees=4, rows=600 if fast else 2000,
                pred_rows=256)
-    env = dict(os.environ, PYTHONPATH="src")
+    # a forced-host-device mesh by design: the child stays on the CPU even
+    # where the parent holds an accelerator
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     res = subprocess.run([sys.executable, "-c", _SCRIPT.format(**cfg)],
                          env=env, capture_output=True, text=True,
                          timeout=1500, cwd=os.path.dirname(

@@ -1,68 +1,23 @@
-"""Version-compatibility shims over drifting jax APIs.
+"""Mesh construction with the axis semantics the protocol is written for.
 
-The repo pins ``jax==0.4.37`` (requirements.txt), but the source is written
-against the modern spellings (``jax.shard_map``, ``jax.set_mesh``,
-positional ``AbstractMesh(sizes, names)``) so an upgrade is a no-op.  Every
-call site goes through this module instead of feature-testing jax inline.
+``jax.make_mesh`` (jax >= 0.7) makes every axis ``Explicit``: shardings then
+become part of each array's type, and the federated programs' slicing of
+party-stacked arrays is refused ("slicing on sharded dims ... not divisible
+by mesh axes").  The protocol bodies are written for ``Auto`` axes — GSPMD
+propagates shardings and ``shard_map`` bodies see plain local arrays — so
+every mesh in the repo is built here.
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Any, Callable
+from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
-def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-              check_vma: bool = True) -> Callable:
-    """``jax.shard_map`` (>=0.6) or ``jax.experimental.shard_map`` (0.4.x).
-
-    ``check_vma`` maps onto the old ``check_rep`` flag — same meaning
-    (verify collective/replication consistency of the body).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
-def abstract_mesh(axis_sizes: tuple, axis_names: tuple):
-    """``AbstractMesh`` across the 0.4->0.7 constructor change.
-
-    New jax takes ``(sizes, names)`` positionally; 0.4.x takes a single
-    tuple of ``(name, size)`` pairs.
-    """
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(tuple(axis_names), tuple(axis_sizes))))
-
-
-def set_mesh(mesh) -> contextlib.AbstractContextManager:
-    """Context manager installing ``mesh`` for sharding-constraint resolution.
-
-    ``jax.set_mesh`` on new jax; on 0.4.x a concrete ``Mesh`` is itself the
-    resource-env context manager that gives ``with_sharding_constraint``
-    its axis names.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
-
-
-def axis_size(axis_name: str) -> Any:
-    """Size of a mapped SPMD axis from inside the mapped function.
-
-    ``lax.axis_size`` only exists on newer jax; ``psum`` of the literal 1 is
-    the portable spelling and constant-folds at trace time (no collective in
-    the lowered program).
-    """
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
+              devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)

@@ -30,10 +30,14 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.core import impurity
 from repro.core.tree import PartyTree
 from repro.core.types import PARTY_AXIS, ForestParams
+
+# The vote contractions select one leaf row per (tree, sample) with a 0/1
+# mask.  A TPU's default f32 matmul is one bf16 pass, which would round the
+# selected leaf counts/values; HIGHEST keeps the selection exact.
+_EXACT = lax.Precision.HIGHEST
 
 
 def tree_leaf_membership(tree: PartyTree, xb_test: jnp.ndarray,
@@ -96,7 +100,8 @@ def _combine_votes(inter: jnp.ndarray, leaf: jnp.ndarray, params: ForestParams,
                         .astype(jnp.int32) - 1)                    # (T, N)
         else:
             # per-tree label by leaf majority, then forest majority (Alg. 4)
-            stats = jnp.einsum("tnl,tlc->tnc", inter.astype(jnp.float32), leaf)
+            stats = jnp.einsum("tnl,tlc->tnc", inter.astype(jnp.float32), leaf,
+                               precision=_EXACT)
             per_tree = jnp.argmax(stats, -1)                       # (T, N)
         if not aggregate:
             return per_tree
@@ -104,10 +109,22 @@ def _combine_votes(inter: jnp.ndarray, leaf: jnp.ndarray, params: ForestParams,
                  jnp.arange(params.n_classes)[None, None, :]).sum(0)
         return jnp.argmax(votes, -1)
     vals = impurity.leaf_value(leaf, params.task)            # (T, L)
-    per_tree = jnp.einsum("tnl,tl->tn", inter.astype(jnp.float32), vals)
+    per_tree = jnp.einsum("tnl,tl->tn", inter.astype(jnp.float32), vals,
+                          precision=_EXACT)
     if not aggregate:
         return per_tree
-    return per_tree.mean(0)                                  # Alg. 8: averaging
+    return tree_mean(per_tree)                               # Alg. 8: averaging
+
+
+def tree_mean(per_tree: jnp.ndarray) -> jnp.ndarray:
+    """Forest average of (T, N) per-tree values, summed in tree order.
+
+    The fixed order makes a row's prediction independent of the program's
+    row count: the order of a ``mean`` reduction on the TPU depends on the
+    shape, and served buckets must agree bit for bit with a full predict."""
+    total, _ = lax.scan(lambda acc, v: (acc + v, None),
+                        jnp.zeros_like(per_tree[0]), per_tree)
+    return total / per_tree.shape[0]
 
 
 def tree_leaf_membership_compact(tree: PartyTree, xb_test: jnp.ndarray,
@@ -168,7 +185,7 @@ def forest_predict_oneround(trees: PartyTree, xb_test: jnp.ndarray,
         leaf = gather_leaf_stats(trees, leaf_idx)
     # === Proposition 1: ONE collective for the whole forest ===
     m = lax.psum(mem.astype(mask_dtype), PARTY_AXIS)
-    n_parties = compat.axis_size(PARTY_AXIS)                 # static, no comm
+    n_parties = lax.axis_size(PARTY_AXIS)                    # static, no comm
     inter = m == jnp.asarray(n_parties, mask_dtype)          # S^l = ∩ S_i^l
     return _combine_votes(inter, leaf, params, aggregate, vote_impl)
 

@@ -48,8 +48,6 @@ def sharded_program(fn: Callable[..., Any], mesh: Mesh, n_party: int,
     Returns the un-jitted program — callers jit/lower it (the AOT serving
     path) or wrap it in ``run_sharded`` for eager use.
     """
-    from repro import compat  # local: compat imports nothing from core
-
     shared_specs = tuple(shared_specs) if shared_specs is not None else \
         (P(),) * n_shared
     if len(shared_specs) != n_shared:
@@ -62,8 +60,8 @@ def sharded_program(fn: Callable[..., Any], mesh: Mesh, n_party: int,
         out = fn(*party, *args[n_party:])
         return jax.tree.map(lambda a: a[None], out)
 
-    return compat.shard_map(local, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=False)
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def run_sharded(fn: Callable[..., Any], party_args: tuple,

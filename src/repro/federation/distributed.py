@@ -429,6 +429,8 @@ class Coordinator:
         self.channels: dict[int, transport.Channel] = {}
         self._procs: list = []
         self._dead: set[int] = set()
+        # party -> the jax platform its worker reported in its hello
+        self.worker_platforms: dict[int, str] = {}
         self._nonce = 0
         self._run_id = 0
         self._bind_id = 0
@@ -445,8 +447,14 @@ class Coordinator:
         src_root = str(Path(__file__).resolve().parents[2])
         ctx = multiprocessing.get_context("spawn")
         old_pp = os.environ.get("PYTHONPATH")
-        os.environ["PYTHONPATH"] = (src_root if not old_pp
-                                    else src_root + os.pathsep + old_pp)
+        # Workers run on the host CPU: on one host an accelerator belongs to
+        # the coordinator process, and a worker that reached for it would
+        # fail or hang on the device the coordinator already holds.
+        child_env = {"PYTHONPATH": (src_root if not old_pp
+                                    else src_root + os.pathsep + old_pp),
+                     "JAX_PLATFORMS": "cpu"}
+        saved = {k: os.environ.get(k) for k in child_env}
+        os.environ.update(child_env)
         try:
             for i in range(self.n_parties):
                 p = ctx.Process(target=_worker_entry,
@@ -454,10 +462,11 @@ class Coordinator:
                 p.start()
                 self._procs.append(p)
         finally:
-            if old_pp is None:
-                os.environ.pop("PYTHONPATH", None)
-            else:
-                os.environ["PYTHONPATH"] = old_pp
+            for k, v in saved.items():
+                if v is None:
+                    os.environ.pop(k, None)
+                else:
+                    os.environ[k] = v
         srv.settimeout(self.connect_timeout)
         try:
             for _ in range(self.n_parties):
@@ -467,6 +476,7 @@ class Coordinator:
                 if hello.get("op") != "hello":
                     raise ProtocolError(f"expected hello, got {hello}")
                 idx = int(hello["party"])
+                self.worker_platforms[idx] = hello.get("platform")
                 ch.party = idx
                 self.channels[idx] = ch
         except (socket.timeout, TimeoutError) as e:

@@ -36,6 +36,7 @@ import os
 import time
 import traceback
 
+import jax
 import numpy as np
 
 from repro.federation import transport
@@ -46,7 +47,8 @@ from repro.observability import trace as tracing
 def worker_main(host: str, port: int, index: int) -> None:
     tracing.TRACER.process = f"party{index}"
     ch = transport.connect(host, port)
-    ch.send({"op": "hello", "party": index})
+    ch.send({"op": "hello", "party": index,
+             "platform": jax.default_backend()})
     binds: dict[int, dict] = {}
     chaos: dict | None = None
     block = None

@@ -104,8 +104,7 @@ def forest_predict_program(substrate, params: ForestParams, *,
             vote_impl=vote_impl, leaf_idx=shared[0] if shared else None)
         return out[None]                                    # (1, T_loc, N)
 
-    from repro import compat
-    inner = compat.shard_map(
+    inner = jax.shard_map(
         predict_local, mesh=substrate.mesh,
         in_specs=(tree_spec, P(PARTY_AXIS)) + shared_specs,
         out_specs=tree_spec, check_vma=False)
@@ -116,7 +115,7 @@ def forest_predict_program(substrate, params: ForestParams, *,
             votes = (per_tree[0][..., None] ==
                      jnp.arange(p.n_classes)[None, None]).sum(0)
             return jnp.argmax(votes, -1)
-        return per_tree[0].mean(0)
+        return prediction.tree_mean(per_tree[0])
     return fn
 
 

@@ -137,8 +137,7 @@ class ShardedSubstrate(InProcessSubstrate):
 
     def context(self):
         """Mesh context for lowering (resolves in-program sharding names)."""
-        from repro import compat
-        return compat.set_mesh(self.mesh)
+        return jax.set_mesh(self.mesh)
 
 
 # ------------------------------------------------------------------- registry

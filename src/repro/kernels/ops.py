@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the kernels, with a histogram backend registry.
 
 ``histogram`` dispatches through ``BACKENDS``, a name -> callable registry:
-  * ``pallas``            — the TPU kernel, compiled when the host really is
-                            a TPU and interpret-mode elsewhere;
-  * ``pallas_interpret``  — the TPU kernel forced through the interpreter
+  * ``pallas``            — the TPU kernel, always compiled (a host with no
+                            TPU refuses it rather than falling back);
+  * ``pallas_interpret``  — the same kernel through the Pallas interpreter
                             (correctness path on any host);
   * ``scatter``           — index-add formulation, fastest on CPU/GPU hosts
                             (used by the single-host simulation path of the
@@ -125,10 +125,8 @@ def _histogram_segment_sum(xb, seg, stats, n_level: int, n_bins: int):
 
 @register_backend("pallas")
 def _histogram_pallas(xb, seg, stats, n_level: int, n_bins: int):
-    # interpret=None: compiled on a real TPU, interpreter elsewhere (CPU
-    # "pallas" runs have always meant interpret=True here — correctness path)
     return _hist_kernel.histogram_pallas(xb, seg, stats, n_level, n_bins,
-                                         interpret=None)
+                                         interpret=False)
 
 
 @register_backend("pallas_interpret")

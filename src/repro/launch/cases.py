@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.configs import registry
 from repro.configs.base import ArchConfig
 from repro.models import sharding, transformer
@@ -81,7 +80,7 @@ class Case:
         outs = sharding.named(mesh, self.out_shardings)
         jitted = jax.jit(self.fn, in_shardings=ins, out_shardings=outs,
                          donate_argnums=self.donate)
-        with compat.set_mesh(mesh):  # resolves in-model sharding constraints
+        with jax.set_mesh(mesh):  # resolves in-model sharding constraints
             return jitted.lower(*self.args)
 
 

@@ -14,17 +14,19 @@ from __future__ import annotations
 
 import jax
 
+from repro import compat
+
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def make_forest_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "trees", "parties") if multi_pod else ("trees", "parties")
-    return jax.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def make_host_mesh(n: int = 1, axes=("data", "model"),
@@ -32,4 +34,4 @@ def make_host_mesh(n: int = 1, axes=("data", "model"),
     """Small in-process mesh for tests (uses however many devices exist)."""
     n = n or len(jax.devices())
     shape = shape or (1, n)
-    return jax.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes)

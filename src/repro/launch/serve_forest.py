@@ -31,6 +31,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import ForestParams, PartyBlock
 from repro.data import make_classification
 from repro.federation import Federation
@@ -86,6 +87,7 @@ def main() -> None:
     ap.add_argument("--id-column", default="id")
     ap.add_argument("--label-column", default="label")
     args = ap.parse_args()
+    enable_compile_cache()
     buckets = tuple(int(b) for b in args.buckets.split(","))
 
     p = ForestParams(n_estimators=args.trees, max_depth=args.depth,

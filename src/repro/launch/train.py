@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import registry
 from repro.configs.base import ArchConfig, reduced
 from repro.data.lm import synthetic_lm_batches
@@ -146,6 +147,7 @@ def main() -> None:
     ap.add_argument("--id-column", default="id")
     ap.add_argument("--label-column", default="label")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.arch == "federated-forest":
         forest_train(args)
         return

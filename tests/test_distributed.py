@@ -74,6 +74,13 @@ def test_fit_predict_bit_identity(dist_fed, task):
                                   np.asarray(sim.predict(ref, xt)))
 
 
+def test_workers_run_on_host_cpu(dist_fed):
+    """Party workers are spawned with JAX_PLATFORMS=cpu: an accelerator on
+    the host belongs to the coordinator, and each hello says so."""
+    coord = dist_fed.substrate.coordinator
+    assert coord.worker_platforms == {i: "cpu" for i in range(M)}
+
+
 def test_distributed_csv_ingest_matches_in_process(dist_fed, tmp_path):
     """Per-party CSV extracts ingested through the party processes (raw
     features and IDs never leave the worker) build the same partition — and

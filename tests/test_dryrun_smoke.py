@@ -13,10 +13,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax
+from repro import compat
 from repro.launch import cases
 
 out = {}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = compat.make_mesh((2, 4), ("data", "model"))
 for arch, shape in [("internlm2-1.8b", "decode_32k"),
                     ("xlstm-350m", "long_500k"),
                     ("qwen2-vl-2b", "prefill_32k")]:
@@ -26,7 +27,7 @@ for arch, shape in [("internlm2-1.8b", "decode_32k"),
     out[f"{arch}:{shape}"] = int(ma.temp_size_in_bytes)
 
 # federated forest protocol on a (trees, parties) mesh
-fmesh = jax.make_mesh((2, 4), ("trees", "parties"))
+fmesh = compat.make_mesh((2, 4), ("trees", "parties"))
 fn, args, _ = cases.forest_case("ff_train", fmesh)
 c = jax.jit(fn).lower(*args).compile()
 out["ff_train"] = int(c.memory_analysis().temp_size_in_bytes)

@@ -380,10 +380,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 import jax, jax.numpy as jnp
+from repro import compat
 from repro.core.types import ForestParams
 from repro.federation import Federation
 
-mesh = jax.make_mesh((2, 4), ("trees", "parties"))
+mesh = compat.make_mesh((2, 4), ("trees", "parties"))
 fed = Federation(parties=4, substrate="sharded", mesh=mesh,
                  hist_impl="scatter")
 p = ForestParams(n_classes=2, n_estimators=2, max_depth=5, n_bins=8)
@@ -406,7 +407,7 @@ jax.jit(pred).lower(trees_shape, xb_test, leaf_idx).compile()
 # over a multi-shard "trees" axis (tree_sharded=False) — executes eagerly
 from repro.core import BoostParams
 from repro.data import make_regression
-bmesh = jax.make_mesh((2, 1), ("trees", "parties"))
+bmesh = compat.make_mesh((2, 1), ("trees", "parties"))
 bfed = Federation(parties=1, substrate="sharded", mesh=bmesh)
 x, y = make_regression(200, 6, seed=0)
 bfed.ingest(x, y, n_bins=8)

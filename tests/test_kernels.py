@@ -47,7 +47,12 @@ def test_resolve_backend_passthrough_and_unknown():
         ops.resolve_backend("warp-histogram")
 
 
-@pytest.mark.parametrize("impl", ["scatter", "pallas", "ref", "segment_sum"])
+@pytest.mark.parametrize("impl", [
+    "scatter",
+    # the compiled kernel needs a TPU; the CPU sweep runs the same kernel
+    # through the interpreter (test_tpu_compile.py compiles it for a v5e)
+    pytest.param("pallas_interpret", id="pallas"),
+    "ref", "segment_sum"])
 def test_histogram_impl_agreement(impl):
     rng = np.random.default_rng(0)
     xb = jnp.asarray(rng.integers(0, 16, (257, 9)), jnp.int32)
@@ -80,7 +85,8 @@ def test_histogram_stats_dtype_bf16_inputs():
     seg = jnp.asarray(rng.integers(0, 2, (128,)), jnp.int32)
     stats = jnp.asarray(rng.normal(size=(128, 2)), jnp.bfloat16)
     want = histogram_ref(xb, seg, stats.astype(jnp.float32), 2, 8)
-    got = histogram_pallas(xb, seg, stats.astype(jnp.float32), 2, 8)
+    got = histogram_pallas(xb, seg, stats.astype(jnp.float32), 2, 8,
+                           interpret=True)
     assert got.dtype == jnp.float32
     np.testing.assert_allclose(got, want, rtol=1e-2, atol=1e-2)
 
@@ -92,7 +98,7 @@ def test_histogram_weighted_totals():
     xb = jnp.asarray(rng.integers(0, 8, (n, 5)), jnp.int32)
     seg = jnp.zeros((n,), jnp.int32)
     stats = jnp.asarray(rng.normal(size=(n, 2)), jnp.float32)
-    h = histogram_pallas(xb, seg, stats, 1, 8)
+    h = histogram_pallas(xb, seg, stats, 1, 8, interpret=True)
     np.testing.assert_allclose(h.sum((0, 2)),
                                jnp.broadcast_to(stats.sum(0), (5, 2)),
                                rtol=1e-4, atol=1e-4)

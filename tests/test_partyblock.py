@@ -438,6 +438,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=6"
 import numpy as np
 import jax
+from repro import compat
 from repro.core import ForestParams, PartyBlock, crypto
 from repro.data import make_classification, make_party_views
 from repro.federation import Federation
@@ -446,7 +447,7 @@ x, y = make_classification(240, 9, 2, seed=5)
 blocks, xa, ya = make_party_views(x, y, 3, overlap=0.8, seed=5)
 p = ForestParams(n_estimators=2, max_depth=4, n_bins=8, seed=3)
 
-mesh = jax.make_mesh((2, 3), ("trees", "parties"))
+mesh = compat.make_mesh((2, 3), ("trees", "parties"))
 fed = Federation(parties=3, substrate="sharded", mesh=mesh, n_bins=8,
                  hist_impl="scatter")
 part = fed.ingest(blocks, validate=True)

@@ -61,8 +61,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro import hlo_analysis
-mesh = jax.make_mesh((4,), ("model",))
+from repro import compat, hlo_analysis
+mesh = compat.make_mesh((4,), ("model",))
 n = 128
 a = jax.ShapeDtypeStruct((n, n), jnp.float32,
                          sharding=NamedSharding(mesh, P(None, "model")))

@@ -116,12 +116,10 @@ def test_training_reduces_ce_end_to_end():
 def test_sharding_rules_divisibility():
     """Every param spec must divide the mesh axes it names (on shapes from
     all 10 archs) — the invariant the dry-run relies on."""
-    from repro import compat
     from repro.configs import registry as reg
     from repro.models import sharding, transformer
     # AbstractMesh: full production topology without needing 256 devices
-    # (constructed via compat — the ctor signature changed across jax versions)
-    mesh = compat.abstract_mesh((16, 16), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     for arch in reg.ARCH_IDS:
         cfg = reg.get(arch)
         shapes = jax.eval_shape(
