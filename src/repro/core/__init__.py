@@ -7,3 +7,6 @@ from repro.core.party import (VerticalPartition, make_vertical_partition,  # noq
 from repro.core.partyblock import (CSVSource, DataSource, PartyBlock,  # noqa: F401
                                    align_party_blocks)
 from repro.core.types import ForestParams, PARTY_AXIS  # noqa: F401
+from repro.observability import profiler as _profiler
+
+_profiler.install()      # program spans reach any jax.profiler session

@@ -24,6 +24,7 @@ import numpy as np
 from repro.core import crypto, impurity, tree
 from repro.core.party import VerticalPartition, make_vertical_partition
 from repro.core.types import ForestParams
+from repro.observability import trace as tracing
 
 
 @dataclasses.dataclass
@@ -62,6 +63,11 @@ class FederatedForest:
 
     # ------------------------------------------------------------------ fit
     def fit(self, partition: VerticalPartition, y: np.ndarray) -> "FederatedForest":
+        """Grow the forest.  Spans: ``fit.prepare`` (label encoding,
+        statistics, master randomness, inputs on the device), then on an
+        in-process substrate ``fit.lower`` (trace + lower) and
+        ``fit.compile`` (XLA compile or persistent-cache load), and
+        ``fit.run`` until the trees are ready."""
         from repro.federation import programs
         # "auto" build knobs resolve against the actual training set — the
         # concrete values land back on self.params so refits/serving see them
@@ -69,23 +75,35 @@ class FederatedForest:
         p = self.params
         if partition.xb.shape[2] == 0:
             raise ValueError("empty feature space")
-        y = np.asarray(y)
-        if self.encrypt_labels and p.task == "classification":
-            y_enc, self._decode = crypto.encode_labels(y, p.n_classes, p.seed)
-        elif self.mask_regression and p.task == "regression":
-            y_enc, self._decode = crypto.mask_regression_targets(y, p.seed)
-        else:
-            y_enc, self._decode = y, lambda v: np.asarray(v)
+        sub = self._sub()
+        with tracing.TRACER.span("fit.prepare", rows=partition.n_samples,
+                                 trees=p.n_estimators):
+            y = np.asarray(y)
+            if self.encrypt_labels and p.task == "classification":
+                y_enc, self._decode = crypto.encode_labels(y, p.n_classes,
+                                                           p.seed)
+            elif self.mask_regression and p.task == "regression":
+                y_enc, self._decode = crypto.mask_regression_targets(y, p.seed)
+            else:
+                y_enc, self._decode = y, lambda v: np.asarray(v)
+            y_stats = impurity.stat_channels(jnp.asarray(y_enc), p.task,
+                                             p.n_classes)
+            weights, feat_sels = self._master_randomness(partition)
+            with sub.context():
+                args = jax.block_until_ready((
+                    jnp.asarray(partition.xb),
+                    jnp.asarray(partition.feat_gid), jnp.asarray(feat_sels),
+                    jnp.asarray(weights), y_stats))
 
-        y_stats = impurity.stat_channels(jnp.asarray(y_enc), p.task, p.n_classes)
-        weights, feat_sels = self._master_randomness(partition)
-
-        run = self._sub().compile(programs.forest_fit_program(self._sub(), p,
-                                                              self.hist_impl))
-        with self._sub().context():
-            self.trees_ = jax.block_until_ready(run(
-                jnp.asarray(partition.xb), jnp.asarray(partition.feat_gid),
-                jnp.asarray(feat_sels), jnp.asarray(weights), y_stats))
+        run = sub.compile(programs.forest_fit_program(sub, p, self.hist_impl))
+        with sub.context():
+            if hasattr(run, "lower"):           # jit: split trace / compile
+                with tracing.TRACER.span("fit.lower"):
+                    lowered = run.lower(*args)
+                with tracing.TRACER.span("fit.compile"):
+                    run = lowered.compile()
+            with tracing.TRACER.span("fit.run"):
+                self.trees_ = jax.block_until_ready(run(*args))
         self.partition_ = partition
         return self
 

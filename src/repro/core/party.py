@@ -25,6 +25,7 @@ import numpy as np
 from repro.core import binning, crypto
 from repro.core.partyblock import (PartyBlock, align_party_blocks,
                                    feature_groups, resolve_blocks)
+from repro.observability import trace as tracing
 
 
 @dataclasses.dataclass
@@ -227,7 +228,9 @@ def partition_from_blocks(blocks, n_bins: int, *,
         x_i = b.x[pos]
         if b.feature_ids is not None:           # party-local column order ->
             x_i = x_i[:, np.argsort(b.feature_ids)]  # ascending global id
-        xb_i, b_i = binning.bin_dataset(x_i, n_bins)
+        with tracing.TRACER.span("ingest.bin", party=b.name,
+                                 rows=x_i.shape[0], features=x_i.shape[1]):
+            xb_i, b_i = binning.bin_dataset(x_i, n_bins)
         xb[i, :, : x_i.shape[1]] = xb_i
         boundaries[g] = b_i
         raw_parts.append(x_i)

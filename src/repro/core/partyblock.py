@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.analysis import runtime as egress_runtime
 from repro.core import crypto
+from repro.observability import trace as tracing
 
 
 @dataclasses.dataclass
@@ -308,30 +309,45 @@ def align_party_blocks(blocks: list[PartyBlock], *,
     order — the raw-matrix compat adapter) skip the hashing pass: the
     identity alignment is returned directly, preserving the caller's row
     order bit-for-bit.
+
+    Spans: ``ingest.hash`` per party's hashing, ``ingest.align`` over the
+    uniqueness checks and the intersection.
     """
+    first = blocks[0].ids
+    if all(b.ids.shape == first.shape and np.array_equal(b.ids, first)
+           for b in blocks[1:]):
+        with tracing.TRACER.span("ingest.align", parties=len(blocks),
+                                 rows=first.size):
+            _check_unique_ids(blocks)
+            if first.size == 0:     # the fast path must keep the loud-error
+                raise ValueError(   # contract, not fall through to binning
+                    f"empty hashed-ID intersection across parties "
+                    f"{[b.name for b in blocks]}: no shared samples to "
+                    f"align")
+        pos = np.arange(len(first), dtype=np.int64)
+        return first.copy(), [pos.copy() for _ in blocks]
+    hashed = []
+    for b in blocks:
+        with tracing.TRACER.span("ingest.hash", party=b.name, rows=b.ids.size):
+            hashed.append(b.hashed_ids(salt))
+    with tracing.TRACER.span("ingest.align", parties=len(blocks)):
+        _check_unique_ids(blocks)
+        try:
+            # uniqueness validated just above, with party names attached
+            positions = crypto.align_ids(*hashed, check_unique=False)
+        except ValueError as e:
+            if "intersection" not in str(e):
+                raise
+            raise ValueError(
+                f"empty hashed-ID intersection across parties "
+                f"{[b.name for b in blocks]}: no shared samples to align "
+                f"(same ID space and salt on every party?)") from e
+    return blocks[0].ids[positions[0]], list(positions)
+
+
+def _check_unique_ids(blocks) -> None:
     for b in blocks:
         if np.unique(b.ids).size != b.ids.size:
             raise ValueError(
                 f"party {b.name!r} has duplicate sample IDs: alignment "
                 f"would be ambiguous — deduplicate before ingest")
-    first = blocks[0].ids
-    if all(b.ids.shape == first.shape and np.array_equal(b.ids, first)
-           for b in blocks[1:]):
-        if first.size == 0:     # the fast path must keep the loud-error
-            raise ValueError(   # contract, not fall through to binning
-                f"empty hashed-ID intersection across parties "
-                f"{[b.name for b in blocks]}: no shared samples to align")
-        pos = np.arange(len(first), dtype=np.int64)
-        return first.copy(), [pos.copy() for _ in blocks]
-    try:
-        # uniqueness already validated above with party names attached
-        positions = crypto.align_ids(*(b.hashed_ids(salt) for b in blocks),
-                                     check_unique=False)
-    except ValueError as e:
-        if "intersection" not in str(e):
-            raise
-        raise ValueError(
-            f"empty hashed-ID intersection across parties "
-            f"{[b.name for b in blocks]}: no shared samples to align "
-            f"(same ID space and salt on every party?)") from e
-    return blocks[0].ids[positions[0]], list(positions)

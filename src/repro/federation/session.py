@@ -138,6 +138,15 @@ class Federation:
         working set, ``sketch_capacity`` the sketch memory/accuracy
         trade-off.  ``ingest_append`` can then land new rows.
         """
+        with tracing.TRACER.span("ingest", category="host",
+                                 parties=self.parties):
+            return self._ingest(data, y, n_bins=n_bins, contiguous=contiguous,
+                                seed=seed, salt=salt, validate=validate,
+                                chunk_rows=chunk_rows,
+                                sketch_capacity=sketch_capacity)
+
+    def _ingest(self, data, y, *, n_bins, contiguous, seed, salt, validate,
+                chunk_rows, sketch_capacity) -> VerticalPartition:
         from repro.streaming import is_chunked_sequence
         if is_chunked_sequence(data):
             if y is not None or not contiguous or seed is not None:

@@ -11,20 +11,22 @@ Three pieces, all stdlib-only on the hot path:
   histograms with pooled quantiles; party snapshots roll up to the
   coordinator through the worker ``telemetry`` op.
 - :mod:`repro.observability.export` — JSONL + Chrome-trace export and
-  the critical-path report behind the ``repro-trace`` CLI, plus the
-  opt-in ``jax.profiler`` hook.
+  the critical-path report behind the ``repro-trace`` CLI.
+
+A ``jax.profiler`` session also records the tracer's spans, as
+``repro.<name>`` events on the profiler's host trace
+(:mod:`repro.observability.profiler`, installed when ``repro.core`` loads).
 """
 from repro.observability.registry import (Counter, Gauge, Histogram,
                                           Registry, REGISTRY)
 from repro.observability.trace import TRACER, Tracer, current_context
 from repro.observability.export import (chrome_trace, critical_path,
                                         export_jsonl, format_report,
-                                        jax_profile, read_jsonl,
-                                        write_chrome_trace)
+                                        read_jsonl, write_chrome_trace)
 
 __all__ = [
     "TRACER", "Tracer", "current_context",
     "REGISTRY", "Registry", "Counter", "Gauge", "Histogram",
     "export_jsonl", "read_jsonl", "chrome_trace", "write_chrome_trace",
-    "critical_path", "format_report", "jax_profile",
+    "critical_path", "format_report",
 ]

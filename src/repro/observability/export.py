@@ -9,20 +9,13 @@ separate tracks.  ``critical_path`` attributes wall-clock to
 comm / compute / host by *self time* (a span's duration minus its
 children's), so nested spans never double count, and breaks the fit
 down per level and per process.
-
-``jax_profile(logdir)`` is the opt-in ``jax.profiler`` hook: a context
-manager that starts a profiler trace when a directory is given and is a
-no-op otherwise (jax is imported lazily so this module stays
-stdlib-only on the disabled path).
 """
 from __future__ import annotations
 
-import contextlib
 import json
 
 __all__ = ["export_jsonl", "read_jsonl", "chrome_trace",
-           "write_chrome_trace", "critical_path", "format_report",
-           "jax_profile"]
+           "write_chrome_trace", "critical_path", "format_report"]
 
 
 def export_jsonl(spans, path):
@@ -181,14 +174,3 @@ def format_report(spans, top: int = 10) -> str:
         lines.append(f"  {s['dur_s'] * 1e3:9.1f} ms  {s['proc']:<12} "
                      f"[{s['cat']}] {s['name']}" + (f"  {attrs}" if attrs else ""))
     return "\n".join(lines)
-
-
-@contextlib.contextmanager
-def jax_profile(logdir):
-    """Opt-in ``jax.profiler`` trace around a block; no-op if logdir falsy."""
-    if not logdir:
-        yield
-        return
-    import jax
-    with jax.profiler.trace(str(logdir)):
-        yield

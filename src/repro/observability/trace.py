@@ -24,6 +24,19 @@ span ids are minted, and — critically — no ``_trace`` key is ever added
 to wire messages (disabled-path traffic is byte-identical to
 uninstrumented code).
 
+Profiler sessions: a hook (``set_profiler_hook``, installed by
+:mod:`repro.observability.profiler` when the jax-importing packages load)
+also arms recording while a ``jax.profiler`` session records.  Each span
+opened then is also opened as a profiler event named ``repro.<name>`` on
+the same thread, so it lands on the trace's host plane, nested as the spans
+nest, on the device trace's clock.  The disabled path pays one probe call
+per span open.
+
+Totals: while recording, the tracer also keeps a count and a sum of
+seconds per span name (``totals()``), which the span buffer's bound does
+not limit; ``add(name, seconds)`` counts a wait the program measured
+between two points it knows.  ``reset()`` clears them.
+
 Privacy: span names/attributes are metadata only.  Attribute values are
 restricted to scalars (str/int/float/bool/None) and short tuples of
 scalars; anything array-like raises ``TypeError``.  The static twin is
@@ -85,12 +98,15 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+#: Prefix of the profiler events that carry program spans.
+PROFILER_PREFIX = "repro."
+
 
 class _SpanHandle:
     """An open span; context manager that records itself on exit."""
 
     __slots__ = ("_tracer", "name", "category", "tid", "sid", "parent",
-                 "t0", "_pc0", "attrs", "_thread")
+                 "t0", "_pc0", "attrs", "_thread", "_event")
 
     def __init__(self, tracer, name, category, tid, sid, parent, attrs):
         self._tracer = tracer
@@ -103,6 +119,7 @@ class _SpanHandle:
         self.t0 = time.time()
         self._pc0 = time.perf_counter()
         self._thread = threading.current_thread().name
+        self._event = None      # open profiler event, if a session records
 
     def set(self, **attrs):
         self.attrs.update(_check_attrs(attrs))
@@ -123,7 +140,8 @@ class Tracer:
     ``enable()``.  Even when disabled, ``attach(ctx)`` with a non-None
     remote context arms recording on that thread — a worker process that
     never saw the env var still records spans for traced coordinator
-    messages.
+    messages — and so does a running profiler session, once a profiler
+    hook is installed.
     """
 
     def __init__(self, enabled: bool | None = None, process: str | None = None):
@@ -134,6 +152,11 @@ class Tracer:
         self._ids = itertools.count(1)
         self._buf = collections.deque(maxlen=_MAX_SPANS)
         self._local = threading.local()
+        # name -> [count, seconds]; fleet cells drain on a thread pool
+        self._totals: dict[str, list] = {}
+        self._totals_lock = threading.Lock()
+        self._probe = None          # () -> bool: a profiler session records
+        self._annotate = None       # name -> profiler event (context manager)
 
     # ------------------------------------------------------------ state
     @property
@@ -147,10 +170,22 @@ class Tracer:
         self._enabled = False
 
     def reset(self):
-        """Drop buffered spans and this thread's context (for tests)."""
+        """Drop buffered spans, the totals and this thread's context."""
         self._buf.clear()
+        with self._totals_lock:
+            self._totals.clear()
         self._local.stack = []
         self._local.remote = 0
+
+    def set_profiler_hook(self, probe, annotate) -> None:
+        """Record while a profiler session records, and mirror each span
+        opened then into it.
+
+        ``probe()`` says whether a session is recording; ``annotate(name)``
+        returns a context manager that opens an event on the session's host
+        trace.  Both come from the caller so this module stays
+        stdlib-only."""
+        self._probe, self._annotate = probe, annotate
 
     def _stack(self):
         st = getattr(self._local, "stack", None)
@@ -160,6 +195,10 @@ class Tracer:
 
     def _active(self) -> bool:
         return self._enabled or getattr(self._local, "remote", 0) > 0
+
+    def _profiling(self) -> bool:
+        probe = self._probe
+        return probe is not None and probe()
 
     def _next_sid(self) -> str:
         return f"{self.process}/{next(self._ids)}"
@@ -181,19 +220,20 @@ class Tracer:
     # ------------------------------------------------------------ spans
     def span(self, name: str, category: str = "host", **attrs):
         """Open a span as a context manager; no-op singleton when off."""
-        if not self._active():
+        profiling = self._profiling()
+        if not (profiling or self._active()):
             return _NOOP
-        return self._begin(name, category, attrs)
+        return self._begin(name, category, attrs, profiling)
 
     def begin(self, name: str, category: str = "host", **attrs):
-        """Manually open a span (pair with ``finish``); None when off.
-
-        For spans whose open/close straddle function boundaries, e.g. a
-        serving wave opened at dispatch and closed at collect.
+        """Manually open a span (pair with ``finish``, on the same thread);
+        None when off.  For spans whose open and close sit in different
+        places of one function, e.g. around a pump with several exits.
         """
-        if not self._active():
+        profiling = self._profiling()
+        if not (profiling or self._active()):
             return None
-        return self._begin(name, category, attrs)
+        return self._begin(name, category, attrs, profiling)
 
     def finish(self, handle):
         if handle is not None and handle is not _NOOP:
@@ -201,12 +241,35 @@ class Tracer:
 
     def event(self, name: str, category: str = "host", **attrs):
         """Record a zero-duration instant span."""
-        if not self._active():
+        profiling = self._profiling()
+        if not (profiling or self._active()):
             return
-        h = self._begin(name, category, attrs)
+        h = self._begin(name, category, attrs, profiling)
         self._finish(h)
 
-    def _begin(self, name, category, attrs):
+    def add(self, name: str, seconds: float) -> None:
+        """Count ``seconds`` under ``name`` in the totals, while recording:
+        a wait the program measured between two points it knows (a
+        request's time in the queue), where no span could be open."""
+        if self._profiling() or self._active():
+            self._count(name, seconds)
+
+    def totals(self) -> dict[str, tuple[int, float]]:
+        """``{name: (count, seconds)}`` of every span finished and every
+        ``add`` while recording since the last ``reset()``."""
+        with self._totals_lock:
+            return {k: (v[0], v[1]) for k, v in self._totals.items()}
+
+    def _count(self, name, seconds):
+        with self._totals_lock:
+            entry = self._totals.get(name)
+            if entry is None:
+                self._totals[name] = [1, seconds]
+            else:
+                entry[0] += 1
+                entry[1] += seconds
+
+    def _begin(self, name, category, attrs, profiling):
         st = self._stack()
         if st:
             tid, parent = st[-1]
@@ -216,10 +279,17 @@ class Tracer:
         h = _SpanHandle(self, name, category, tid, sid, parent,
                         _check_attrs(attrs))
         st.append((tid, sid))
+        if profiling:
+            h._event = self._annotate(PROFILER_PREFIX + name)
+            h._event.__enter__()
         return h
 
     def _finish(self, h):
         dur = time.perf_counter() - h._pc0
+        if h._event is not None:
+            h._event.__exit__(None, None, None)
+            h._event = None
+        self._count(h.name, dur)
         st = self._stack()
         # Pop back to (and including) this span; tolerates overlapping
         # manual begin/finish by searching instead of asserting order.

@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import os
 import time
 from typing import Any, Callable
 
@@ -56,7 +55,6 @@ from repro.federation.substrate import ShardedSubstrate, SimulatedSubstrate
 from repro.federation.transport import PartyUnavailableError
 from repro.observability import registry as telemetry
 from repro.observability import trace as tracing
-from repro.observability.export import jax_profile
 from repro.serving import plan
 from repro.serving.config import ServeConfig
 
@@ -99,9 +97,6 @@ class InFlightWave:
     # extra per-wave facts recorded by the dispatch path (e.g. the degraded
     # serving flag + dead-party list) — merged into the wave_stats entry
     info: dict | None = None
-    # open trace span (tracing.TRACER.begin), finished at collect; None
-    # when tracing is disabled
-    span: Any = None
 
 
 class ModelServer:
@@ -143,10 +138,6 @@ class ModelServer:
         self._request_fp = n_features_per_party
         self._n_inflight = 0
         self._wave_info = None
-        # opt-in jax.profiler hook: set a directory (or export
-        # REPRO_JAX_PROFILE=<dir>) and serve_binned wraps its wave pump in
-        # a profiler trace
-        self.profile_dir = os.environ.get("REPRO_JAX_PROFILE") or None
         # telemetry handles bound once — the per-wave path must not pay a
         # registry name lookup per wave
         self._m_waves = telemetry.REGISTRY.counter("serving.waves")
@@ -261,7 +252,8 @@ class ModelServer:
         are padded to the wave's bucket and handed to the AOT executable.
         JAX dispatch is asynchronous, so this returns as soon as the launch
         is enqueued — host work for the next wave (binning, coalescing,
-        padding) overlaps device execution of this one."""
+        padding) overlaps device execution of this one.  Span:
+        ``serve.dispatch``."""
         xb_parts = np.asarray(xb_parts)
         m, n, fp = xb_parts.shape
         if m != self.n_parties:
@@ -272,18 +264,17 @@ class ModelServer:
                 f"chop oversized requests into waves (serve_binned does)")
         self._check_fp(fp)
         bucket = self._bucket_for(n)
-        compiled = self._executable(bucket)
-        if n < bucket:
-            xb_parts = np.pad(xb_parts, ((0, 0), (0, bucket - n), (0, 0)))
-        span = tracing.TRACER.begin("serve.wave", category="compute",
-                                    bucket=bucket, rows=n)
-        t0 = time.perf_counter()
-        self._wave_info = None
-        out = self._execute(compiled, jnp.asarray(xb_parts))
+        with tracing.TRACER.span("serve.dispatch", bucket=bucket, rows=n):
+            compiled = self._executable(bucket)
+            if n < bucket:
+                xb_parts = np.pad(xb_parts, ((0, 0), (0, bucket - n), (0, 0)))
+            t0 = time.perf_counter()
+            self._wave_info = None
+            out = self._execute(compiled, jnp.asarray(xb_parts))
         self._n_inflight += 1
         return InFlightWave(out=out, bucket=bucket, n_rows=n, t0=t0,
                             inflight_at_dispatch=self._n_inflight,
-                            info=self._wave_info, span=span)
+                            info=self._wave_info)
 
     def _execute(self, compiled, xbt):
         """Launch one compiled wave — the failure seam.  ForestServer
@@ -296,25 +287,27 @@ class ModelServer:
 
         Under async dispatch ``latency_s`` spans launch -> ready, so for
         waves that queued behind earlier in-flight work it includes queueing
-        time (``inflight_at_dispatch`` records the ring depth at launch)."""
-        out = jax.block_until_ready(wave.out)
-        dt = time.perf_counter() - wave.t0
-        tracing.TRACER.finish(wave.span)
-        self._n_inflight -= 1
-        self._m_waves.inc()
-        self._m_rows.inc(wave.n_rows)
-        self._m_latency.observe(dt)
-        entry = {
-            "bucket": wave.bucket, "n_rows": wave.n_rows,
-            "t0": wave.t0, "latency_s": dt,
-            "rows_per_s": wave.n_rows / max(dt, 1e-12),
-            "inflight": wave.inflight_at_dispatch,
-            "comm_bytes": self._wave_comm_bytes(wave.bucket),
-        }
-        if wave.info:
-            entry.update(wave.info)
-        self.wave_stats.append(entry)
-        return self._finalize(self._strip(out, wave.n_rows))
+        time (``inflight_at_dispatch`` records the ring depth at launch).
+        Span: ``serve.collect``."""
+        with tracing.TRACER.span("serve.collect", bucket=wave.bucket,
+                                 rows=wave.n_rows):
+            out = jax.block_until_ready(wave.out)
+            dt = time.perf_counter() - wave.t0
+            self._n_inflight -= 1
+            self._m_waves.inc()
+            self._m_rows.inc(wave.n_rows)
+            self._m_latency.observe(dt)
+            entry = {
+                "bucket": wave.bucket, "n_rows": wave.n_rows,
+                "t0": wave.t0, "latency_s": dt,
+                "rows_per_s": wave.n_rows / max(dt, 1e-12),
+                "inflight": wave.inflight_at_dispatch,
+                "comm_bytes": self._wave_comm_bytes(wave.bucket),
+            }
+            if wave.info:
+                entry.update(wave.info)
+            self.wave_stats.append(entry)
+            return self._finalize(self._strip(out, wave.n_rows))
 
     def abandon(self, waves) -> None:
         """Collect-and-discard in-flight handles whose results are no longer
@@ -378,13 +371,12 @@ class ModelServer:
         ring: collections.deque[InFlightWave] = collections.deque()
         outs, lo = [], 0
         try:
-            with jax_profile(self.profile_dir):
-                while lo < n or ring:
-                    while lo < n and len(ring) < k:   # fill the ring
-                        hi = min(lo + self.buckets[-1], n)
-                        ring.append(self.dispatch_wave(xb_parts[:, lo:hi]))
-                        lo = hi
-                    outs.append(self.collect(ring.popleft()))  # backpressure
+            while lo < n or ring:
+                while lo < n and len(ring) < k:       # fill the ring
+                    hi = min(lo + self.buckets[-1], n)
+                    ring.append(self.dispatch_wave(xb_parts[:, lo:hi]))
+                    lo = hi
+                outs.append(self.collect(ring.popleft()))  # backpressure
         except BaseException:
             self.abandon(ring)                        # keep inflight honest
             raise

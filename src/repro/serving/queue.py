@@ -65,6 +65,7 @@ class _Pending:
     t_submit: float
     sent: int = 0               # rows dispatched into in-flight waves
     done: int = 0               # rows collected + scattered back
+    waited: bool = False        # queue.wait counted (first dispatch)
     out: np.ndarray | None = None
 
     @property
@@ -177,30 +178,33 @@ class RequestQueue:
 
         Returns ((M, rows, Fp) array, [(pending, start, take), ...]) or
         (None, None) when every pending row is already in flight.  A
-        binning failure is attributed to the exact request being binned."""
+        binning failure is attributed to the exact request being binned.
+        Span: ``serve.bin``."""
         cap = min(self.max_wave_rows, self.server.buckets[-1])
         wave, spans, rows = [], [], 0
-        with self._lock:
-            pending = list(self._pending)
-        for p in pending:
-            remaining = p.n_rows - p.sent
-            if remaining == 0:          # fully dispatched (or zero-row)
-                continue
-            take = min(remaining, cap - rows)
-            if take == 0:               # wave is full
-                break
-            try:
-                wave.append(p.party_rows(self.server, p.sent, take))
-            except Exception as err:
-                raise PoisonedWaveError(
-                    f"request {p.rid} failed to bin: {err}",
-                    rids=(p.rid,), stage="bin") from err
-            spans.append((p, p.sent, take))
-            p.sent += take
-            rows += take
-        if not wave:
-            return None, None
-        return np.concatenate(wave, axis=1), spans
+        with tracing.TRACER.span("serve.bin") as bin_span:
+            with self._lock:
+                pending = list(self._pending)
+            for p in pending:
+                remaining = p.n_rows - p.sent
+                if remaining == 0:          # fully dispatched (or zero-row)
+                    continue
+                take = min(remaining, cap - rows)
+                if take == 0:               # wave is full
+                    break
+                try:
+                    wave.append(p.party_rows(self.server, p.sent, take))
+                except Exception as err:
+                    raise PoisonedWaveError(
+                        f"request {p.rid} failed to bin: {err}",
+                        rids=(p.rid,), stage="bin") from err
+                spans.append((p, p.sent, take))
+                p.sent += take
+                rows += take
+            if not wave:
+                return None, None
+            bin_span.set(rows=rows, requests=len(spans))
+            return np.concatenate(wave, axis=1), spans
 
     def _scatter(self, out: np.ndarray, spans) -> None:
         """Write one collected wave's (decoded) rows back to its requests."""
@@ -264,6 +268,12 @@ class RequestQueue:
                             rids=[p.rid for p, _, _ in spans],
                             stage="dispatch") from err
                     ring.append((handle, spans))
+                    t_sent = time.perf_counter()
+                    for p, _, _ in spans:
+                        if not p.waited:            # once, even after a
+                            p.waited = True         # rollback re-dispatch
+                            tracing.TRACER.add("queue.wait",
+                                               t_sent - p.t_submit)
                 if not ring:                        # nothing in flight:
                     self._retire(results)           # zero-row stragglers
                     break
