@@ -76,8 +76,12 @@ class FederatedForest:
         if partition.xb.shape[2] == 0:
             raise ValueError("empty feature space")
         sub = self._sub()
-        with tracing.TRACER.span("fit.prepare", rows=partition.n_samples,
-                                 trees=p.n_estimators):
+        party_cols = partition.xb.shape[2]
+        with tracing.TRACER.span(
+                "fit.prepare", rows=partition.n_samples, trees=p.n_estimators,
+                hist_cols=tree.hist_columns(p, partition.n_features,
+                                            party_cols),
+                party_cols=party_cols):
             y = np.asarray(y)
             if self.encrypt_labels and p.task == "classification":
                 y_enc, self._decode = crypto.encode_labels(y, p.n_classes,
@@ -89,6 +93,7 @@ class FederatedForest:
             y_stats = impurity.stat_channels(jnp.asarray(y_enc), p.task,
                                              p.n_classes)
             weights, feat_sels = self._master_randomness(partition)
+            self._check_feature_budget(feat_sels)
             with sub.context():
                 args = jax.block_until_ready((
                     jnp.asarray(partition.xb),
@@ -120,7 +125,7 @@ class FederatedForest:
         p = self.params
         n, f = partition.n_samples, partition.n_features
         t = p.n_estimators
-        k = max(1, int(np.ceil(p.max_features * f)))
+        k = p.features_per_tree(f)
         weights = np.ones((t, n))
         feat_sels = np.zeros((t, f), dtype=bool)
         for i in range(t):
@@ -130,6 +135,19 @@ class FederatedForest:
                                          minlength=n)
             feat_sels[i, rng.choice(f, size=k, replace=False)] = True
         return weights.astype(np.float32), feat_sels
+
+    def _check_feature_budget(self, feat_sels: np.ndarray) -> None:
+        """``tree.build_tree`` histograms at most ``features_per_tree(F)``
+        columns per party and tree; a subsample above that budget would lose
+        features inside the program, so it is refused here."""
+        budget = self.params.features_per_tree(feat_sels.shape[1])
+        drawn = np.asarray(feat_sels).sum(axis=1)
+        over = np.flatnonzero(drawn > budget)
+        if over.size:
+            raise ValueError(
+                f"tree {over[0]} selects {drawn[over[0]]} features, above the "
+                f"per-tree budget of {budget} (max_features="
+                f"{self.params.max_features} of {feat_sels.shape[1]})")
 
     def _fit_fingerprint(self, partition: VerticalPartition,
                          y: np.ndarray) -> str:
@@ -235,6 +253,7 @@ class FederatedForest:
             y_enc, self._decode = y, lambda v: np.asarray(v)
         y_stats = impurity.stat_channels(jnp.asarray(y_enc), p.task, p.n_classes)
         weights, feat_sels = self._master_randomness(partition)
+        self._check_feature_budget(feat_sels)
         fingerprint = self._fit_fingerprint(partition, y)
 
         from repro.federation import programs

@@ -32,6 +32,16 @@ collectives, which keeps the cross-party protocol (and therefore the built
 which histogram row a live node's samples accumulate into, never which
 samples they are.
 
+Feature compaction: the master's feature subsample is fixed for a tree, so
+a party's split search needs only the columns it selected.  Where the
+per-tree budget ``k`` (``ForestParams.features_per_tree``) is below the
+party's padded width, each tree gathers its selected columns (ascending,
+filler slots masked) once before the level loop and every level histograms
+those ``k`` columns, not all ``Fp``.  Each column's histogram, gains and best
+split are computed independently of the others, so the trees are
+bit-identical to a search over every column; the winning compact index maps
+back to the party-local column before it is recorded.
+
 Distributed model storage is preserved exactly: a party records (feature,
 threshold) only for nodes it owns (``has_split``); the shared structure
 (``is_leaf`` + heap layout) is what the paper calls "keeping the node
@@ -202,6 +212,14 @@ def _split_search_frontier(xb, seg, wstats, fmask, feat_gid, width, cap,
     return g_lv[:width], gid_lv[:width], bin_lv[:width], floc_lv[:width]
 
 
+def hist_columns(params: ForestParams, n_features: int,
+                 party_cols: int) -> int:
+    """Columns each party's split search histograms per tree: the per-tree
+    feature budget, capped at the party's ``party_cols`` padded columns.
+    Below ``party_cols``, ``build_tree`` gathers the selected columns."""
+    return min(party_cols, params.features_per_tree(n_features))
+
+
 def build_tree(xb: jnp.ndarray, feat_gid: jnp.ndarray, feat_sel: jnp.ndarray,
                weight: jnp.ndarray, y_stats: jnp.ndarray,
                params: ForestParams, *,
@@ -211,13 +229,15 @@ def build_tree(xb: jnp.ndarray, feat_gid: jnp.ndarray, feat_sel: jnp.ndarray,
     Args:
       xb:       (N, Fp) uint8 party-local binned features (padded).
       feat_gid: (Fp,) int32 global feature ids, -1 for padding.
-      feat_sel: (F,) bool master's per-tree feature subsample (global ids).
+      feat_sel: (F,) bool master's per-tree feature subsample (global ids);
+                it selects at most ``params.features_per_tree(F)`` features
+                (checked on the host by ``FederatedForest.fit``).
       weight:   (N,) float32 bootstrap weights (0 excludes a sample).
       y_stats:  (N, C) label stat channels — shared across parties (the paper
                 copies encrypted labels to every client, §3.1).
       hist_impl: histogram backend override; None uses ``params.hist_impl``.
     """
-    n, _ = xb.shape
+    n, fp = xb.shape
     c = y_stats.shape[-1]
     nn = params.n_nodes
     me = lax.axis_index(PARTY_AXIS)
@@ -226,7 +246,17 @@ def build_tree(xb: jnp.ndarray, feat_gid: jnp.ndarray, feat_sel: jnp.ndarray,
 
     fmask = (feat_gid >= 0) & feat_sel[jnp.clip(feat_gid, 0)]
     wstats = y_stats.astype(jnp.float32) * weight[:, None]
-    xb_i32 = xb.astype(jnp.int32)
+    k = hist_columns(params, feat_sel.shape[0], fp)
+    if k < fp:
+        # the tree's selected columns, ascending; slots past the party's
+        # selection count are fillers that can never win a split
+        sel_idx, = jnp.nonzero(fmask, size=k, fill_value=0)
+        fmask = jnp.arange(k) < fmask.sum()
+        feat_gid = jnp.where(fmask, feat_gid[sel_idx], -1)
+        xb_i32 = jnp.take(xb, sel_idx, axis=1).astype(jnp.int32)
+    else:
+        sel_idx = None
+        xb_i32 = xb.astype(jnp.int32)
 
     node = jnp.zeros((n,), jnp.int32)
     is_leaf = jnp.zeros((nn,), bool)
@@ -279,8 +309,11 @@ def build_tree(xb: jnp.ndarray, feat_gid: jnp.ndarray, feat_sel: jnp.ndarray,
 
         mine = do_split & (owner_lv == me)  # "receive the split message" (Alg.1)
         has_split = lax.dynamic_update_slice(has_split, mine, (off,))
+        # a winning floc_loc indexes xb_i32's columns; record the party's own
+        party_floc = (floc_loc if sel_idx is None
+                      else jnp.take(sel_idx, floc_loc, mode="clip"))
         split_floc = lax.dynamic_update_slice(
-            split_floc, jnp.where(mine, floc_loc, -1), (off,))
+            split_floc, jnp.where(mine, party_floc, -1), (off,))
         split_bin = lax.dynamic_update_slice(
             split_bin, jnp.where(mine, bin_loc, -1), (off,))
         owner = lax.dynamic_update_slice(

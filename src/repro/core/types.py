@@ -6,6 +6,7 @@ hashable, frozen params object (used as a static argument).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Literal
 
 Task = Literal["classification", "regression"]
@@ -129,6 +130,12 @@ class ForestParams:
         regression:     (w, w*y, w*y^2) — enough for variance/SSE splits.
         """
         return self.n_classes if self.task == "classification" else 3
+
+    def features_per_tree(self, n_features: int) -> int:
+        """Features the master draws for each tree out of ``n_features``:
+        ``ceil(max_features * n_features)``, at least one.  ``build_tree``
+        sizes its per-tree split search by the same number."""
+        return max(1, math.ceil(self.max_features * n_features))
 
     def level_slice(self, depth: int) -> tuple[int, int]:
         """(offset, width) of the nodes at ``depth`` in heap layout."""

@@ -13,7 +13,9 @@ The load-bearing claims:
     named span, one ``serve.dispatch`` per wave and one ``queue.wait`` per
     request — also when a failed collect rolls a request back and a later
     drain dispatches it again — and the session changes no fitted tree and
-    no served answer.
+    no served answer;
+  * ``fit.prepare`` reports the columns each party's split search
+    histograms against the party's padded width.
 """
 import contextlib
 import glob
@@ -24,7 +26,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import ForestParams
+from repro.core import ForestParams, PartyBlock
 from repro.data import make_classification, make_party_views
 from repro.federation import Federation
 from repro.observability import TRACER, Tracer
@@ -289,3 +291,29 @@ def test_queue_wait_counted_once_after_rollback(monkeypatch):
     assert sorted(out) == sorted(rids)
     assert totals["serve.dispatch"][0] == 2
     assert totals["queue.wait"][0] == len(rids)
+
+
+@pytest.mark.parametrize("max_features,hist_cols", [(0.2, 2), (1.0, 6)])
+def test_fit_prepare_reports_hist_columns(max_features, hist_cols):
+    """``fit.prepare`` carries the columns each party's split search
+    histograms (``hist_cols``) beside its padded width (``party_cols``):
+    parties of 4 and 6 features pad to 6 columns, and ``max_features`` 0.2
+    draws 2 of the 10 features a tree."""
+    x, y = make_classification(120, 10, seed=6)
+    blocks = [PartyBlock(name=name, x=x[:, lo:hi], ids=np.arange(len(x)),
+                         y=y if name == "a" else None)
+              for name, lo, hi in (("a", 0, 4), ("b", 4, 10))]
+    fed = Federation(parties=2, n_bins=16)
+    fed.ingest(blocks, salt="cols")
+    TRACER.reset()
+    TRACER.enable()
+    try:
+        fed.fit(ForestParams(n_estimators=2, max_depth=2, n_bins=16,
+                             max_features=max_features, seed=2))
+        spans = [s for s in TRACER.spans() if s["name"] == "fit.prepare"]
+    finally:
+        TRACER.disable()
+        TRACER.reset()
+    assert len(spans) == 1
+    assert spans[0]["attrs"]["hist_cols"] == hist_cols
+    assert spans[0]["attrs"]["party_cols"] == 6

@@ -4,7 +4,8 @@ The TPU compiler is installed with jax; it compiles for a described,
 unattached ``v5e:2x2`` topology.  Interpret-mode tests (test_kernels.py)
 cannot see what Mosaic refuses — sub-tile reshapes, illegal block tilings —
 so these compiles guard the kernel's layout at the shapes a fit issues:
-F <= 8 and F > 8 per party, node levels 1 and 256 (``frontier_cap``),
+F <= 8 and F > 8 per party (all padded columns, or a tree's compacted
+feature subsample), node levels 1 and 256 (``frontier_cap``),
 32 bins, 2 (classification) and 3 (regression) stat channels.
 
 The topology is described inside a fixture, never at import: only one
@@ -45,6 +46,7 @@ def one_chip():
     (65_536, 8, 16, 2),       # F <= 8 (once refused: sub-tile shape cast)
     (65_536, 24, 1, 3),       # F > 8 (once refused: (512, 8) xb block), root
     (156_198, 84, 256, 2),    # target-marketing e-commerce party, cap level
+    (156_198, 10, 256, 2),    # the same, compacted to a tree's 10 features
     (515_345, 23, 256, 3),    # Year Prediction quarter, regression channels
 ])
 def test_histogram_compiles_for_v5e(one_chip, n, f, n_level, c):
