@@ -52,3 +52,43 @@ def bin_dataset(x: np.ndarray, n_bins: int) -> tuple[np.ndarray, np.ndarray]:
     """Fit + apply quantile binning. Returns (binned uint8, boundaries)."""
     b = quantile_boundaries(x, n_bins)
     return apply_bins(x, b), b
+
+
+# ------------------------------------------------------ categorical columns
+# A categorical column is not cut at quantiles: its bin is its category's
+# code, the rank of the value among the column's sorted distinct training
+# values.  The dictionary (those sorted values) stays with the party that
+# owns the column, exactly like a numeric column's edges.
+
+def category_dictionary(col: np.ndarray, n_bins: int) -> np.ndarray:
+    """Sorted distinct values of one categorical column: code c is
+    ``dictionary[c]``.  At most ``n_bins - 1`` categories fit, so that code
+    ``len(dictionary)`` stays free for values unseen at fit time."""
+    try:
+        cats = np.unique(np.asarray(col))
+    except TypeError as e:
+        raise ValueError(f"categorical values must be mutually orderable "
+                         f"(all numbers or all strings): {e}") from e
+    if cats.size > n_bins - 1:
+        raise ValueError(
+            f"categorical column has {cats.size} categories, above "
+            f"n_bins - 1 = {n_bins - 1}: each category needs a bin of its "
+            f"own plus one for unseen values — raise n_bins")
+    return cats
+
+
+def apply_categories(col: np.ndarray, dictionary: np.ndarray) -> np.ndarray:
+    """uint8 codes of ``col`` under a fit-time dictionary; a value the
+    dictionary lacks gets ``len(dictionary)``, a code no training row has,
+    so every set split sends it right."""
+    col = np.asarray(col)
+    k = len(dictionary)
+    if k == 0:
+        return np.zeros(col.shape, np.uint8)
+    try:
+        pos = np.searchsorted(dictionary, col)
+        seen = dictionary[np.minimum(pos, k - 1)] == col
+    except TypeError as e:
+        raise ValueError(f"categorical values do not compare with the "
+                         f"fit-time categories: {e}") from e
+    return np.where(seen, pos, k).astype(np.uint8)

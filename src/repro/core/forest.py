@@ -75,13 +75,15 @@ class FederatedForest:
         p = self.params
         if partition.xb.shape[2] == 0:
             raise ValueError("empty feature space")
+        cat_mask = self._cat_mask(partition)
         sub = self._sub()
         party_cols = partition.xb.shape[2]
         with tracing.TRACER.span(
                 "fit.prepare", rows=partition.n_samples, trees=p.n_estimators,
                 hist_cols=tree.hist_columns(p, partition.n_features,
                                             party_cols),
-                party_cols=party_cols):
+                party_cols=party_cols,
+                cat_cols=len(partition.categories or ())):
             y = np.asarray(y)
             if self.encrypt_labels and p.task == "classification":
                 y_enc, self._decode = crypto.encode_labels(y, p.n_classes,
@@ -94,13 +96,15 @@ class FederatedForest:
                                              p.n_classes)
             weights, feat_sels = self._master_randomness(partition)
             self._check_feature_budget(feat_sels)
+            party = (partition.xb, partition.feat_gid) + (
+                () if cat_mask is None else (cat_mask,))
             with sub.context():
                 args = jax.block_until_ready((
-                    jnp.asarray(partition.xb),
-                    jnp.asarray(partition.feat_gid), jnp.asarray(feat_sels),
+                    *map(jnp.asarray, party), jnp.asarray(feat_sels),
                     jnp.asarray(weights), y_stats))
 
-        run = sub.compile(programs.forest_fit_program(sub, p, self.hist_impl))
+        run = sub.compile(programs.forest_fit_program(
+            sub, p, self.hist_impl, categorical=cat_mask is not None))
         with sub.context():
             if hasattr(run, "lower"):           # jit: split trace / compile
                 with tracing.TRACER.span("fit.lower"):
@@ -111,6 +115,21 @@ class FederatedForest:
                 self.trees_ = jax.block_until_ready(run(*args))
         self.partition_ = partition
         return self
+
+    def _cat_mask(self, partition: VerticalPartition):
+        """The partition's (M, Fp) categorical-column mask, or None.  Set
+        splits are exact by CART's ordering rule only for two classes and
+        for regression, so other class counts are refused."""
+        cat_mask = partition.cat_mask
+        p = self.params
+        if cat_mask is not None and p.task == "classification" \
+                and p.n_classes != 2:
+            raise ValueError(
+                f"categorical columns need n_classes == 2 or regression "
+                f"(got n_classes={p.n_classes}): no ordering rule finds "
+                f"the best category set for more classes, so the forest "
+                f"could not be lossless")
+        return cat_mask
 
     def _master_randomness(self, partition: VerticalPartition):
         """Paper Alg. 2: master samples rows (bootstrap) + per-tree features.
@@ -163,6 +182,8 @@ class FederatedForest:
         for a in (partition.xb, partition.feat_gid, partition.boundaries,
                   np.asarray(y)):
             h.update(np.ascontiguousarray(a).tobytes())
+        if partition.categories:
+            h.update(repr(sorted(partition.categories)).encode())
         h.update(repr(dataclasses.replace(
             self.params, n_estimators=0)).encode())
         h.update(repr((self.encrypt_labels, self.mask_regression)).encode())
@@ -246,6 +267,7 @@ class FederatedForest:
         from repro import ckpt
         self.params = self.params.resolved(partition.n_samples)
         p = self.params
+        cat_mask = self._cat_mask(partition)
         y = np.asarray(y)
         if self.encrypt_labels and p.task == "classification":
             y_enc, self._decode = crypto.encode_labels(y, p.n_classes, p.seed)
@@ -257,8 +279,10 @@ class FederatedForest:
         fingerprint = self._fit_fingerprint(partition, y)
 
         from repro.federation import programs
-        run = self._sub().compile(programs.forest_fit_program(self._sub(), p,
-                                                              self.hist_impl))
+        run = self._sub().compile(programs.forest_fit_program(
+            self._sub(), p, self.hist_impl, categorical=cat_mask is not None))
+        party = (jnp.asarray(partition.xb), jnp.asarray(partition.feat_gid)) \
+            + (() if cat_mask is None else (jnp.asarray(cat_mask),))
 
         def restore(done):
             # PartyTree stack shapes are fully determined by (M, done, params)
@@ -274,6 +298,9 @@ class FederatedForest:
                 split_bin=sds((m, done, nn), jnp.int32),
                 owner=sds((m, done, nn), jnp.int32),
                 split_gid=sds((m, done, nn), jnp.int32))
+            if cat_mask is not None:
+                like = tree.CategoricalPartyTree(*like, sds(
+                    (m, done, nn, tree.cat_words(p.n_bins)), jnp.uint32))
             return ckpt.restore_checkpoint(ckpt_dir, done, like)
 
         chunks: list = []
@@ -297,8 +324,7 @@ class FederatedForest:
             start = done
         for lo in range(start, p.n_estimators, trees_per_chunk):
             hi = min(lo + trees_per_chunk, p.n_estimators)
-            part_trees = run(jnp.asarray(partition.xb),
-                             jnp.asarray(partition.feat_gid),
+            part_trees = run(*party,
                              jnp.asarray(feat_sels[lo:hi]),
                              jnp.asarray(weights[lo:hi]), y_stats)
             chunks.append(part_trees)

@@ -46,6 +46,11 @@ class VerticalPartition:
       party_names: per-party identifiers in party-axis order (canonical:
                   sorted).  Serving matches per-party request blocks to
                   fit-time parties by name (``bin_party_blocks``).
+      categories: global feature id -> sorted distinct fit-time values of
+                  each categorical column (its dictionary: a value's bin is
+                  its index, an unseen value's the dictionary's length);
+                  None when every column is numeric.  Kept, like
+                  ``boundaries``, by the owning party in a deployment.
     """
 
     xb: np.ndarray
@@ -54,6 +59,7 @@ class VerticalPartition:
     boundaries: np.ndarray
     raw_parts: list[np.ndarray] | None = None
     party_names: tuple[str, ...] | None = None
+    categories: dict[int, np.ndarray] | None = None
 
     @property
     def n_parties(self) -> int:
@@ -69,10 +75,35 @@ class VerticalPartition:
         n_bins-1 inner edges)."""
         return int(self.boundaries.shape[1]) + 1
 
+    @property
+    def cat_mask(self) -> np.ndarray | None:
+        """(M, Fp) bool: which party-local columns are categorical; None
+        when every column is numeric."""
+        if not self.categories:
+            return None
+        return np.isin(self.feat_gid, list(self.categories))
+
     def bin_test(self, x_test: np.ndarray) -> np.ndarray:
         """Bin a raw test matrix (N_t, F) and partition it like training data."""
-        xb = binning.apply_bins(x_test, self.boundaries)
+        xb = self._apply_fit_bins(x_test, np.arange(self.n_features))
         return _partition_binned(xb, self.feat_gid)
+
+    def _apply_fit_bins(self, x: np.ndarray, gids: np.ndarray) -> np.ndarray:
+        """uint8 codes of raw columns ``x`` (global ids ``gids``): numeric
+        ones against their fit-time edges, categorical ones through their
+        fit-time dictionaries."""
+        if not self.categories:
+            return binning.apply_bins(x, self.boundaries[gids])
+        x = np.asarray(x)
+        is_cat = np.isin(gids, list(self.categories))
+        out = np.empty(x.shape, np.uint8)
+        if (~is_cat).any():
+            out[:, ~is_cat] = binning.apply_bins(
+                _numeric(x[:, ~is_cat]), self.boundaries[gids[~is_cat]])
+        for j in np.flatnonzero(is_cat):
+            out[:, j] = binning.apply_categories(
+                x[:, j], self.categories[int(gids[j])])
+        return out
 
     def split_raw(self, x: np.ndarray) -> list[np.ndarray]:
         """Split a raw (N, F) matrix into per-party column blocks, matching
@@ -87,7 +118,9 @@ class VerticalPartition:
         (the inverse of split_raw; needs ``raw_parts``)."""
         if self.raw_parts is None:
             raise ValueError("this partition was built without raw_parts")
-        out = np.empty((self.n_samples, self.n_features), dtype=np.float64)
+        text = any(rp.dtype.kind in "OUS" for rp in self.raw_parts)
+        out = np.empty((self.n_samples, self.n_features),
+                       dtype=object if text else np.float64)
         for i, rp in enumerate(self.raw_parts):
             out[:, self.feat_gid[i][self.feat_gid[i] >= 0]] = rp
         return out
@@ -165,8 +198,7 @@ class VerticalPartition:
         out = np.zeros((m, len(common), fp), dtype=np.uint8)
         for i, x_i in enumerate(parts):
             gid = self.feat_gid[i][self.feat_gid[i] >= 0]
-            out[i, :, : len(gid)] = binning.apply_bins(
-                x_i, self.boundaries[gid])
+            out[i, :, : len(gid)] = self._apply_fit_bins(x_i, gid)
         return common, out
 
 
@@ -223,14 +255,24 @@ def partition_from_blocks(blocks, n_bins: int, *,
     m, fp = feat_gid.shape
     xb = np.zeros((m, len(common), fp), dtype=np.uint8)
     boundaries = np.zeros((n_features, max(n_bins - 1, 0)), dtype=np.float64)
+    categories: dict[int, np.ndarray] = {}
     raw_parts = []
     for i, (b, pos, g) in enumerate(zip(blocks, positions, groups)):
         x_i = b.x[pos]
+        is_cat = np.zeros(b.n_features, bool)
+        is_cat[list(b.categorical)] = True
         if b.feature_ids is not None:           # party-local column order ->
-            x_i = x_i[:, np.argsort(b.feature_ids)]  # ascending global id
-        with tracing.TRACER.span("ingest.bin", party=b.name,
-                                 rows=x_i.shape[0], features=x_i.shape[1]):
-            xb_i, b_i = binning.bin_dataset(x_i, n_bins)
+            order = np.argsort(b.feature_ids)   # ascending global id
+            x_i, is_cat = x_i[:, order], is_cat[order]
+        if not is_cat.any():
+            with tracing.TRACER.span("ingest.bin", party=b.name,
+                                     rows=x_i.shape[0],
+                                     features=x_i.shape[1]):
+                xb_i, b_i = binning.bin_dataset(x_i, n_bins)
+        else:
+            xb_i, b_i, dicts = bin_mixed_columns(x_i, is_cat, n_bins,
+                                                 party=b.name)
+            categories.update({int(g[j]): d for j, d in dicts.items()})
         xb[i, :, : x_i.shape[1]] = xb_i
         boundaries[g] = b_i
         raw_parts.append(x_i)
@@ -238,7 +280,8 @@ def partition_from_blocks(blocks, n_bins: int, *,
     part = VerticalPartition(xb=xb, feat_gid=feat_gid,
                              n_features=n_features, boundaries=boundaries,
                              raw_parts=raw_parts,
-                             party_names=tuple(b.name for b in blocks))
+                             party_names=tuple(b.name for b in blocks),
+                             categories=categories or None)
     if validate:
         _assert_party_local_binning_lossless(part, n_bins)
 
@@ -254,13 +297,58 @@ def partition_from_blocks(blocks, n_bins: int, *,
     return part, y, common
 
 
+def bin_mixed_columns(x: np.ndarray, is_cat: np.ndarray, n_bins: int, *,
+                      party: str):
+    """Bin a block whose columns ``is_cat`` are categorical: quantile bins
+    for the others (span ``ingest.bin``), each categorical column's own
+    dictionary and codes (span ``ingest.categories``).  Returns ``(xb,
+    boundaries, dictionaries)``; a categorical column's boundaries row is
+    zeros, and ``dictionaries`` maps its column index to its dictionary."""
+    xb = np.zeros(x.shape, np.uint8)
+    edges = np.zeros((x.shape[1], max(n_bins - 1, 0)), np.float64)
+    num = ~is_cat
+    with tracing.TRACER.span("ingest.bin", party=party, rows=x.shape[0],
+                             features=int(num.sum())):
+        if num.any():
+            xb[:, num], edges[num] = binning.bin_dataset(_numeric(x[:, num]),
+                                                         n_bins)
+    dicts = {}
+    with tracing.TRACER.span("ingest.categories", party=party,
+                             rows=x.shape[0], columns=int(is_cat.sum())):
+        for j in np.flatnonzero(is_cat):
+            try:
+                dicts[int(j)] = binning.category_dictionary(x[:, j], n_bins)
+            except ValueError as e:
+                raise ValueError(f"party {party!r}, column {j}: {e}") from e
+            xb[:, j] = binning.apply_categories(x[:, j], dicts[int(j)])
+    return xb, edges, dicts
+
+
+def _numeric(x: np.ndarray) -> np.ndarray:
+    try:
+        return np.asarray(x, np.float64)
+    except (TypeError, ValueError) as e:
+        raise ValueError(f"a column not declared categorical holds a value "
+                         f"that is not a number: {e}") from e
+
+
 def _assert_party_local_binning_lossless(part: VerticalPartition,
                                          n_bins: int) -> None:
     """Binning is per-feature, so party-local binning of aligned blocks must
     equal central binning of the assembled matrix — assert it (guarded
     behind ``validate=True``: it re-bins the whole dataset).  Raises, not
     ``assert``: the check must survive ``python -O``."""
-    xb_central, b_central = binning.bin_dataset(part.dense_raw(), n_bins)
+    if part.categories:
+        is_cat = np.isin(np.arange(part.n_features), list(part.categories))
+        xb_central, b_central, dicts = bin_mixed_columns(
+            part.dense_raw(), is_cat, n_bins, party="central")
+        if any(not np.array_equal(d, part.categories[j])
+               for j, d in dicts.items()):
+            raise AssertionError(
+                "party-local category dictionaries diverge from central "
+                "binning")
+    else:
+        xb_central, b_central = binning.bin_dataset(part.dense_raw(), n_bins)
     if not np.array_equal(part.boundaries, b_central):
         raise AssertionError(
             "party-local boundaries diverge from central binning")

@@ -51,6 +51,11 @@ class PartyBlock:
         uses this to preserve the original column encoding); when omitted,
         ingestion assigns contiguous ids in canonical party order.
       feature_names: optional (f_i,) display names (CSV headers keep them).
+      categorical: party-local indices of the block's categorical columns.
+        Their values may be numbers or strings (``x`` is then an object
+        array whose other columns hold numbers); ingest codes each such
+        column by its own sorted distinct values, a dictionary that stays
+        with the party like its quantile edges.
     """
 
     name: str
@@ -59,13 +64,17 @@ class PartyBlock:
     y: np.ndarray | None = None
     feature_ids: np.ndarray | None = None
     feature_names: tuple[str, ...] | None = None
+    categorical: tuple[int, ...] = ()
 
     def __post_init__(self) -> None:
         # keep float inputs at their own precision (binning casts to float64
         # internally either way, so losslessness is unaffected; coercing
         # float32 silos would double their memory), promote everything else
+        # but the object/string blocks that carry categorical values
         self.x = np.asarray(self.x)
-        if not np.issubdtype(self.x.dtype, np.floating):
+        self.categorical = tuple(sorted({int(j) for j in self.categorical}))
+        if not np.issubdtype(self.x.dtype, np.floating) and not (
+                self.categorical and self.x.dtype.kind in "OUS"):
             self.x = self.x.astype(np.float64)
         self.ids = np.asarray(self.ids).reshape(-1)
         if self.x.ndim != 2:
@@ -75,6 +84,13 @@ class PartyBlock:
             raise ValueError(
                 f"party {self.name!r}: {len(self.ids)} sample IDs for "
                 f"{self.x.shape[0]} feature rows")
+        if self.categorical and not (0 <= self.categorical[0]
+                                     and self.categorical[-1]
+                                     < self.x.shape[1]):
+            raise ValueError(
+                f"party {self.name!r}: categorical columns "
+                f"{list(self.categorical)} outside its {self.x.shape[1]} "
+                f"columns")
         if self.y is not None:
             self.y = np.asarray(self.y).reshape(-1)
             if len(self.y) != self.x.shape[0]:

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import impurity
-from repro.core.tree import PartyTree
+from repro.core.tree import PartyTree, category_member
 from repro.core.types import PARTY_AXIS, ForestParams
 
 # The vote contractions select one leaf row per (tree, sample) with a 0/1
@@ -58,8 +58,18 @@ def tree_leaf_membership(tree: PartyTree, xb_test: jnp.ndarray,
         floc = jnp.clip(lax.dynamic_slice(tree.split_floc, (off,), (width,)), 0)
         bins = lax.dynamic_slice(tree.split_bin, (off,), (width,))
         vals = xb[:, floc]                                   # (N, width)
-        left_ok = ~has[None] | (vals <= bins[None])          # foreign => both
-        right_ok = ~has[None] | (vals > bins[None])
+        if tree.split_cats is None:
+            left_ok = ~has[None] | (vals <= bins[None])      # foreign => both
+            right_ok = ~has[None] | (vals > bins[None])
+        else:                       # a set split: left iff the code is in it
+            cats = lax.dynamic_slice(tree.split_cats, (off, 0),
+                                     (width, tree.split_cats.shape[-1]))
+            set_split = (cats != 0).any(-1)[None]
+            member = category_member(cats, jnp.arange(width)[None], vals)
+            left_ok = ~has[None] | jnp.where(set_split, member,
+                                             vals <= bins[None])
+            right_ok = ~has[None] | jnp.where(set_split, ~member,
+                                              vals > bins[None])
         parts.append(cur & leaf_lv[None])                    # leaves stop here
         alive = cur & ~leaf_lv[None]
         cur = jnp.stack([alive & left_ok, alive & right_ok],
@@ -203,7 +213,12 @@ def forest_predict_classical(trees: PartyTree, xb_test: jnp.ndarray,
             floc = jnp.clip(tree.split_floc[node], 0)
             bins = tree.split_bin[node]
             vals = jnp.take_along_axis(xb, floc[:, None], axis=1)[:, 0]
-            go_r_loc = jnp.where(has, (vals > bins).astype(jnp.int32), 0)
+            go_r = vals > bins
+            if tree.split_cats is not None:
+                set_split = (tree.split_cats != 0).any(-1)[node]
+                go_r = jnp.where(set_split, ~category_member(
+                    tree.split_cats, node, vals), go_r)
+            go_r_loc = jnp.where(has, go_r.astype(jnp.int32), 0)
             go_r = lax.psum(go_r_loc, PARTY_AXIS)  # one round per level (!)
             split_here = tree.owner[node] >= 0     # structure is shared
             node = jnp.where(split_here, 2 * node + 1 + go_r, node)

@@ -42,6 +42,17 @@ split are computed independently of the others, so the trees are
 bit-identical to a search over every column; the winning compact index maps
 back to the party-local column before it is recorded.
 
+Categorical columns: a column the partition marks categorical holds
+category codes, and its split is a set of categories, not a threshold.  The
+same histogram feeds it; per node the categories are put in CART's order
+(``impurity.category_order``) and the usual prefix scan picks the best set,
+recorded as a bitset over the bins (``split_cats``) held by the split's
+owner alone, like a threshold.  Routing sends a row right when its code is
+not in the set, so a category with no rows at a node (a value unseen at fit
+time too) always goes right.  Such a fit returns a ``CategoricalPartyTree``;
+without categorical columns none of this is traced, the fit returns a
+``PartyTree`` and its ``split_cats`` reads None.
+
 Distributed model storage is preserved exactly: a party records (feature,
 threshold) only for nodes it owns (``has_split``); the shared structure
 (``is_leaf`` + heap layout) is what the paper calls "keeping the node
@@ -73,6 +84,52 @@ class PartyTree(NamedTuple):
     split_bin: jnp.ndarray    # (nn,)  int32  — split bin   (mine only)
     owner: jnp.ndarray        # (nn,)  int32  — master view: owning party
     split_gid: jnp.ndarray    # (nn,)  int32  — master view: encoded feature id
+
+    @property
+    def split_cats(self) -> None:
+        """Threshold splits only: no category sets."""
+        return None
+
+
+class CategoricalPartyTree(NamedTuple):
+    """A ``PartyTree`` grown over categorical columns: the same fields, and
+    each set split's left category set."""
+
+    is_leaf: jnp.ndarray
+    leaf_stats: jnp.ndarray
+    has_split: jnp.ndarray
+    split_floc: jnp.ndarray
+    split_bin: jnp.ndarray    # a set split's last scan position going left
+    owner: jnp.ndarray
+    split_gid: jnp.ndarray
+    # (nn, W) uint32 — bit c of word c // 32 is set when code c goes left
+    # (mine only; zeros elsewhere and at threshold splits)
+    split_cats: jnp.ndarray
+
+
+def cat_words(n_bins: int) -> int:
+    """uint32 words of a category bitset over ``n_bins`` codes."""
+    return -(-n_bins // 32)
+
+
+def category_member(words: jnp.ndarray, node: jnp.ndarray,
+                    code: jnp.ndarray) -> jnp.ndarray:
+    """Whether ``code`` lies in the left set of node ``node``: ``words`` is a
+    (nodes, W) uint32 bitset table, ``node`` and ``code`` int32 arrays that
+    broadcast together."""
+    w = words.shape[-1]
+    word = words.reshape(-1)[node * w + (code >> 5)]
+    return ((word >> (code & 31).astype(jnp.uint32)) & 1) == 1
+
+
+def _pack_bits(bits: jnp.ndarray) -> jnp.ndarray:
+    """(..., B) bool -> (..., W) uint32 bitset words."""
+    b = bits.shape[-1]
+    w = cat_words(b)
+    bits = jnp.pad(bits, [(0, 0)] * (bits.ndim - 1) + [(0, w * 32 - b)])
+    bits = bits.reshape(bits.shape[:-1] + (w, 32)).astype(jnp.uint32)
+    return (bits << jnp.arange(32, dtype=jnp.uint32)).sum(-1,
+                                                          dtype=jnp.uint32)
 
 
 def _local_argbest(gains: jnp.ndarray, feat_gid: jnp.ndarray):
@@ -127,8 +184,36 @@ def reduce_level(g_all, gid_all, bin_all, cnt, params: ForestParams):
     return do_split, owner_lv, gid_best, bin_best
 
 
+def _best_splits(hist, fmask, feat_gid, is_cat, params):
+    """Each node's best local split from its (L, F, B, C) histogram:
+    ``(gain, gid, bin, floc)``.  With categorical columns (``is_cat``) each
+    column's bins enter the prefix scan in ``impurity.category_order``
+    (numeric bins as they are), the winner's left set comes back as bitset
+    words too, and a categorical winner's ``bin`` is the last scan position
+    that goes left."""
+    if is_cat is not None:
+        with jax.named_scope("split.categorical"):
+            order = impurity.category_order(hist, is_cat, params.task)
+            hist = jnp.take_along_axis(hist, order[..., None], axis=2)
+    gains = impurity.split_gains(hist, params.task, params.min_samples_leaf)
+    gains = jnp.where(fmask[None, :, None], gains, -jnp.inf)
+    best = _local_argbest(gains, feat_gid)
+    if is_cat is None:
+        return best
+    _, _, bin_, floc = best
+    with jax.named_scope("split.categorical"):
+        lv, k, b = order.shape
+        f = jnp.clip(floc, 0, k - 1)
+        scan = jnp.take_along_axis(order, f[:, None, None], axis=1)[:, 0]
+        prefix = jnp.arange(b)[None, :] <= bin_[:, None]
+        left = jnp.zeros((lv, b), bool).at[
+            jnp.arange(lv)[:, None], scan].set(prefix)
+        words = _pack_bits(left & is_cat[f][:, None])
+    return (*best, words)
+
+
 def _split_search_dense(xb, seg, wstats, fmask, feat_gid, width, params,
-                        hist_impl, prev_hist):
+                        hist_impl, prev_hist, is_cat=None):
     """Seed path: histogram every heap slot of the level at once."""
     fp_dim = xb.shape[1]
     if params.hist_subtraction and prev_hist is not None:
@@ -146,13 +231,11 @@ def _split_search_dense(xb, seg, wstats, fmask, feat_gid, width, params,
     else:
         hist = ops.histogram(xb, seg, wstats, width, params.n_bins,
                              impl=hist_impl)
-    gains = impurity.split_gains(hist, params.task, params.min_samples_leaf)
-    gains = jnp.where(fmask[None, :, None], gains, -jnp.inf)
-    return _local_argbest(gains, feat_gid), hist
+    return _best_splits(hist, fmask, feat_gid, is_cat, params), hist
 
 
 def _split_search_frontier(xb, seg, wstats, fmask, feat_gid, width, cap,
-                           params, hist_impl):
+                           params, hist_impl, is_cat=None):
     """Compacted path: histogram ``cap`` live slots per pass, scatter back.
 
     Live node j (heap-level index, any routed sample) gets compact slot
@@ -181,35 +264,32 @@ def _split_search_frontier(xb, seg, wstats, fmask, feat_gid, width, cap,
         return k * cap < n_live
 
     def body(state):
-        k, g_lv, gid_lv, bin_lv, floc_lv = state
+        k, *found = state
         lo = k * cap
         in_pass = (sslot >= lo) & (sslot < lo + cap)
         seg_k = jnp.where(in_pass, sslot - lo, -1)
         hist = ops.histogram(xb, seg_k, wstats, cap, params.n_bins,
                              impl=hist_impl)
-        gains = impurity.split_gains(hist, params.task,
-                                     params.min_samples_leaf)
-        gains = jnp.where(fmask[None, :, None], gains, -jnp.inf)
-        g_c, gid_c, bin_c, floc_c = _local_argbest(gains, feat_gid)
+        best = _best_splits(hist, fmask, feat_gid, is_cat, params)
         # slot -> heap-level node of THIS pass (cap is the dump row)
         node_in_pass = occ & (slot_of_node >= lo) & (slot_of_node < lo + cap)
         tgt = jnp.where(node_in_pass, slot_of_node - lo, cap)
         inv = jnp.full((cap + 1,), width, jnp.int32).at[tgt].set(
             jnp.where(node_in_pass, nil_idx, width))[:cap]
         # scatter results back to heap order (width is the dump row)
-        g_lv = g_lv.at[inv].set(g_c)
-        gid_lv = gid_lv.at[inv].set(gid_c)
-        bin_lv = bin_lv.at[inv].set(bin_c)
-        floc_lv = floc_lv.at[inv].set(floc_c)
-        return k + 1, g_lv, gid_lv, bin_lv, floc_lv
+        found = [lv.at[inv].set(c) for lv, c in zip(found, best)]
+        return (k + 1, *found)
 
     init = (jnp.int32(0),
             jnp.full((width + 1,), -jnp.inf, jnp.float32),
             jnp.full((width + 1,), _BIG, jnp.int32),
             jnp.full((width + 1,), _BIG, jnp.int32),
             jnp.full((width + 1,), _BIG, jnp.int32))
-    _, g_lv, gid_lv, bin_lv, floc_lv = lax.while_loop(cond, body, init)
-    return g_lv[:width], gid_lv[:width], bin_lv[:width], floc_lv[:width]
+    if is_cat is not None:
+        init += (jnp.zeros((width + 1, cat_words(params.n_bins)),
+                           jnp.uint32),)
+    found = lax.while_loop(cond, body, init)[1:]
+    return tuple(lv[:width] for lv in found)
 
 
 def hist_columns(params: ForestParams, n_features: int,
@@ -223,7 +303,8 @@ def hist_columns(params: ForestParams, n_features: int,
 def build_tree(xb: jnp.ndarray, feat_gid: jnp.ndarray, feat_sel: jnp.ndarray,
                weight: jnp.ndarray, y_stats: jnp.ndarray,
                params: ForestParams, *,
-               hist_impl: str | None = None) -> PartyTree:
+               hist_impl: str | None = None,
+               cat_mask: jnp.ndarray | None = None) -> PartyTree:
     """Build one tree, SPMD over PARTY_AXIS.
 
     Args:
@@ -236,6 +317,8 @@ def build_tree(xb: jnp.ndarray, feat_gid: jnp.ndarray, feat_sel: jnp.ndarray,
       y_stats:  (N, C) label stat channels — shared across parties (the paper
                 copies encrypted labels to every client, §3.1).
       hist_impl: histogram backend override; None uses ``params.hist_impl``.
+      cat_mask: (Fp,) bool, the party's categorical columns; None when the
+                partition has none (a ``PartyTree`` is then returned).
     """
     n, fp = xb.shape
     c = y_stats.shape[-1]
@@ -254,9 +337,12 @@ def build_tree(xb: jnp.ndarray, feat_gid: jnp.ndarray, feat_sel: jnp.ndarray,
         fmask = jnp.arange(k) < fmask.sum()
         feat_gid = jnp.where(fmask, feat_gid[sel_idx], -1)
         xb_i32 = jnp.take(xb, sel_idx, axis=1).astype(jnp.int32)
+        is_cat = (None if cat_mask is None
+                  else jnp.take(cat_mask, sel_idx) & fmask)
     else:
         sel_idx = None
         xb_i32 = xb.astype(jnp.int32)
+        is_cat = cat_mask
 
     node = jnp.zeros((n,), jnp.int32)
     is_leaf = jnp.zeros((nn,), bool)
@@ -266,6 +352,8 @@ def build_tree(xb: jnp.ndarray, feat_gid: jnp.ndarray, feat_sel: jnp.ndarray,
     split_bin = jnp.full((nn,), -1, jnp.int32)
     owner = jnp.full((nn,), -1, jnp.int32)
     split_gid = jnp.full((nn,), -1, jnp.int32)
+    split_cats = (None if is_cat is None else
+                  jnp.zeros((nn, cat_words(params.n_bins)), jnp.uint32))
     prev_hist = None  # parent-level histograms (hist_subtraction)
 
     for d in range(params.max_depth + 1):
@@ -290,14 +378,16 @@ def build_tree(xb: jnp.ndarray, feat_gid: jnp.ndarray, feat_sel: jnp.ndarray,
         # histogram (cap < width); shallow levels keep the seed's dense path.
         cap = min(width, n, params.frontier_cap or width)
         if params.frontier_cap and cap < width:
-            g_loc, gid_loc, bin_loc, floc_loc = _split_search_frontier(
+            found = _split_search_frontier(
                 xb_i32, seg, wstats, fmask, feat_gid, width, cap, params,
-                hist_impl)
+                hist_impl, is_cat)
             prev_hist = None  # compacted levels retain no dense parent hist
         else:
-            (g_loc, gid_loc, bin_loc, floc_loc), prev_hist = \
+            found, prev_hist = \
                 _split_search_dense(xb_i32, seg, wstats, fmask, feat_gid,
-                                    width, params, hist_impl, prev_hist)
+                                    width, params, hist_impl, prev_hist,
+                                    is_cat)
+        g_loc, gid_loc, bin_loc, floc_loc = found[:4]
 
         # ---- the paper's master: gather -> argmax -> notify, as collectives
         g_all = lax.all_gather(g_loc, PARTY_AXIS)          # (M, width)
@@ -320,6 +410,10 @@ def build_tree(xb: jnp.ndarray, feat_gid: jnp.ndarray, feat_sel: jnp.ndarray,
             owner, jnp.where(do_split, owner_lv.astype(jnp.int32), -1), (off,))
         split_gid = lax.dynamic_update_slice(
             split_gid, jnp.where(do_split, gid_best, -1), (off,))
+        if is_cat is not None:
+            cats_lv = jnp.where(mine[:, None], found[4], 0)
+            split_cats = lax.dynamic_update_slice(split_cats, cats_lv,
+                                                  (off, 0))
 
         # ---- owner computes the partition; one psum broadcasts it ----------
         # (paper Alg.2: "Receive split indices from client j and broadcast")
@@ -329,18 +423,27 @@ def build_tree(xb: jnp.ndarray, feat_gid: jnp.ndarray, feat_sel: jnp.ndarray,
         mine_s = in_lvl & mine[nil_c]
         vals = jnp.take_along_axis(
             xb_i32, floc_lv[nil_c][:, None], axis=1)[:, 0]
-        go_r_loc = jnp.where(mine_s, (vals > bin_lv[nil_c]).astype(jnp.int32), 0)
+        go_r = vals > bin_lv[nil_c]
+        if is_cat is not None:      # a set split: right unless in the set
+            set_split = (cats_lv != 0).any(-1)
+            go_r = jnp.where(set_split[nil_c],
+                             ~category_member(cats_lv, nil_c, vals), go_r)
+        go_r_loc = jnp.where(mine_s, go_r.astype(jnp.int32), 0)
         go_r = lax.psum(go_r_loc, PARTY_AXIS)  # exactly one party contributes
         advance = in_lvl & do_split[nil_c]
         node = jnp.where(advance, 2 * node + 1 + go_r, node)
 
-    return PartyTree(is_leaf, leaf_stats, has_split, split_floc, split_bin,
-                     owner, split_gid)
+    fields = (is_leaf, leaf_stats, has_split, split_floc, split_bin, owner,
+              split_gid)
+    if split_cats is None:
+        return PartyTree(*fields)
+    return CategoricalPartyTree(*fields, split_cats)
 
 
 def build_forest(xb, feat_gid, feat_sels, weights, y_stats,
                  params: ForestParams, *,
-                 hist_impl: str | None = None) -> PartyTree:
+                 hist_impl: str | None = None,
+                 cat_mask=None) -> PartyTree:
     """SPMD bagging loop: stack T trees (leading axis T on every leaf).
 
     ``lax.map`` keeps HLO size O(1) in the number of trees and bounds peak
@@ -353,7 +456,7 @@ def build_forest(xb, feat_gid, feat_sels, weights, y_stats,
     def one(args):
         sel, w = args
         return build_tree(xb, feat_gid, sel, w, y_stats, params,
-                          hist_impl=hist_impl)
+                          hist_impl=hist_impl, cat_mask=cat_mask)
 
     tpb = params.trees_per_batch
     t = feat_sels.shape[0]
@@ -374,6 +477,17 @@ def build_forest(xb, feat_gid, feat_sels, weights, y_stats,
         lambda a: a.reshape((n_chunks * tpb,) + a.shape[2:])[:t], out)
 
 
-def fit_spmd(params: ForestParams, hist_impl: str | None = None):
-    """Returns the party-local SPMD fit function (for vmap or shard_map)."""
-    return functools.partial(build_forest, params=params, hist_impl=hist_impl)
+def fit_spmd(params: ForestParams, hist_impl: str | None = None,
+             categorical: bool = False):
+    """Returns the party-local SPMD fit function (for vmap or shard_map):
+    ``fn(xb, feat_gid, feat_sels, weights, y_stats)``, or with
+    ``categorical`` ``fn(xb, feat_gid, cat_mask, feat_sels, weights,
+    y_stats)`` (``cat_mask`` is a party argument like ``feat_gid``)."""
+    if not categorical:
+        return functools.partial(build_forest, params=params,
+                                 hist_impl=hist_impl)
+
+    def fit(xb, feat_gid, cat_mask, feat_sels, weights, y_stats):
+        return build_forest(xb, feat_gid, feat_sels, weights, y_stats,
+                            params, hist_impl=hist_impl, cat_mask=cat_mask)
+    return fit

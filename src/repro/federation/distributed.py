@@ -1076,6 +1076,10 @@ class DistributedSubstrate:
     def ingest_blocks(self, sources, n_bins: int, *,
                       salt: str = crypto.DEFAULT_SALT,
                       validate: bool = False):
+        if any(getattr(b, "categorical", ()) for b in sources):
+            raise ValueError(
+                "categorical columns are not supported on the distributed "
+                "substrate: ingest them on 'simulated' or 'sharded'")
         return distributed_ingest(self.coordinator, sources, n_bins,
                                   salt=salt, validate=validate)
 

@@ -36,27 +36,35 @@ def party0(out):
 
 def forest_fit_program(substrate, params: ForestParams,
                        hist_impl: str | None = None, *,
-                       tree_sharded: bool = True):
+                       tree_sharded: bool = True, categorical: bool = False):
     """fn(xb, feat_gid, feat_sel, weights, y_stats) -> PartyTree stack.
 
     ``tree_sharded=False`` keeps the per-tree args/outputs replicated across
     a mesh's "trees" axis — for callers whose tree count doesn't divide it
-    (boosting fits one tree per round)."""
+    (boosting fits one tree per round).  ``categorical=True`` takes the
+    partition's (M, Fp) ``cat_mask`` as a third party argument,
+    fn(xb, feat_gid, cat_mask, feat_sel, weights, y_stats), and grows set
+    splits on those columns (in-process substrates only)."""
     if params.needs_resolution:
         raise ValueError(
             "frontier_cap/trees_per_batch='auto' resolve at fit time from "
             "the training set; pass params.resolved(n_samples) to build a "
             "program directly")
-    fit_fn = tree.fit_spmd(params, hist_impl)
+    if categorical and substrate.name == "distributed":
+        raise ValueError(
+            "categorical columns are not supported on the distributed "
+            "substrate: fit them on 'simulated' or 'sharded'")
+    fit_fn = tree.fit_spmd(params, hist_impl, categorical)
+    n_party = 3 if categorical else 2
     if substrate.mesh is None:
         from repro.federation import distributed
         return substrate.program(
-            fit_fn, 2, 3,
+            fit_fn, n_party, 3,
             distributed=distributed.forest_fit_spec(params, hist_impl))
     tree_ax = substrate.tree_axis if tree_sharded else None
     per_tree = P(tree_ax) if tree_ax else P()
     out = P(PARTY_AXIS, tree_ax) if tree_ax else P(PARTY_AXIS)
-    return substrate.program(fit_fn, 2, 3,
+    return substrate.program(fit_fn, n_party, 3,
                              shared_specs=(per_tree, per_tree, P()),
                              out_specs=out)
 

@@ -289,6 +289,10 @@ class Federation:
         the fitted handle conforms to the Estimator protocol."""
         partition, y = self._training_set(partition, y)
         self._check_binning(spec, partition)
+        if partition.categories and not isinstance(spec, ForestParams):
+            raise ValueError(
+                f"categorical columns are supported by random forests "
+                f"(ForestParams) only, not {type(spec).__name__}")
         model = self._model_for(self._apply_session(spec), **model_kw)
         with tracing.TRACER.span(f"fit.{type(spec).__name__}",
                                  category="host",

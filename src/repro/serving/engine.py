@@ -48,7 +48,7 @@ import numpy as np
 
 from repro.ckpt import checkpoint as ckpt
 from repro.core import prediction
-from repro.core.tree import PartyTree
+from repro.core.tree import CategoricalPartyTree, PartyTree
 from repro.core.types import ForestParams
 from repro.federation import programs
 from repro.federation.substrate import ShardedSubstrate, SimulatedSubstrate
@@ -67,18 +67,21 @@ def load_forest_trees(ckpt_dir: str, step: int | None = None) -> PartyTree:
 
     PartyTree is a NamedTuple, so its checkpoint keys are the field names
     (".is_leaf", ".leaf_stats", ...) — enough to reconstruct it without a
-    caller-provided ``like`` pytree."""
+    caller-provided ``like`` pytree.  A forest grown over categorical
+    columns also has ``.split_cats`` and comes back as a
+    ``CategoricalPartyTree``."""
     if step is None:
         step = ckpt.latest_step(ckpt_dir)
         if step is None:
             raise FileNotFoundError(f"no checkpoints under {ckpt_dir}")
     flat = ckpt.peek_checkpoint(ckpt_dir, step)
-    keys = [f".{name}" for name in PartyTree._fields]
+    kind = CategoricalPartyTree if ".split_cats" in flat else PartyTree
+    keys = [f".{name}" for name in kind._fields]
     if sorted(flat) != sorted(keys):
         raise ValueError(
             f"checkpoint at {ckpt_dir} step {step} is not a bare PartyTree "
             f"(keys {sorted(flat)})")
-    return PartyTree(*(jnp.asarray(flat[k]) for k in keys))
+    return kind(*(jnp.asarray(flat[k]) for k in keys))
 
 
 @dataclasses.dataclass

@@ -85,6 +85,11 @@ def scan_source(source, *, chunk_rows: int = DEFAULT_CHUNK_ROWS,
     name = n_feat = fids = fnames = has_y = sk = None
     ids_parts, hash_parts, y_parts = [], [], []
     for k, chunk in enumerate(src.iter_chunks(chunk_rows)):
+        if chunk.categorical:
+            raise ValueError(
+                f"party {chunk.name!r}: streaming ingest does not support "
+                f"categorical columns (their dictionaries would have to "
+                f"merge across chunks); ingest the blocks in memory")
         if name is None:
             name, n_feat = chunk.name, chunk.n_features
             fids, fnames = chunk.feature_ids, chunk.feature_names

@@ -64,13 +64,15 @@ class ArraySource:
         if b.n_samples == 0:
             yield PartyBlock(name=b.name, x=b.x, ids=b.ids, y=b.y,
                              feature_ids=b.feature_ids,
-                             feature_names=b.feature_names)
+                             feature_names=b.feature_names,
+                             categorical=b.categorical)
             return
         for lo in range(0, b.n_samples, rows):
             yield PartyBlock(
                 name=b.name, x=b.x[lo:lo + rows], ids=b.ids[lo:lo + rows],
                 y=None if b.y is None else b.y[lo:lo + rows],
-                feature_ids=b.feature_ids, feature_names=b.feature_names)
+                feature_ids=b.feature_ids, feature_names=b.feature_names,
+                categorical=b.categorical)
 
 
 @dataclasses.dataclass

@@ -15,7 +15,9 @@ The load-bearing claims:
     drain dispatches it again — and the session changes no fitted tree and
     no served answer;
   * ``fit.prepare`` reports the columns each party's split search
-    histograms against the party's padded width.
+    histograms against the party's padded width, and its categorical
+    columns; ``ingest.categories`` times each party's category
+    dictionaries apart from its quantile binning.
 """
 import contextlib
 import glob
@@ -317,3 +319,40 @@ def test_fit_prepare_reports_hist_columns(max_features, hist_cols):
     assert len(spans) == 1
     assert spans[0]["attrs"]["hist_cols"] == hist_cols
     assert spans[0]["attrs"]["party_cols"] == 6
+
+
+@pytest.mark.parametrize("categorical", [True, False])
+def test_ingest_categories_span_and_cat_cols(categorical):
+    """A party with a categorical column opens ``ingest.categories`` once,
+    beside its ``ingest.bin``; ``fit.prepare`` counts the categorical
+    columns (0, and no ``ingest.categories``, for a numeric table)."""
+    n = 120
+    x, y = make_classification(n, 6, seed=8)
+    ids = np.arange(n)
+    xa = np.empty((n, 3), object)
+    xa[:, 0] = np.array(list("pqrs"))[ids % 4] if categorical else x[:, 5]
+    xa[:, 1:] = x[:, :2]
+    blocks = [PartyBlock(name="a", x=xa, ids=ids, y=y,
+                         categorical=(0,) if categorical else ()),
+              PartyBlock(name="b", x=x[:, 2:5], ids=ids)]
+    fed = Federation(parties=2, n_bins=16)
+    TRACER.reset()
+    TRACER.enable()
+    try:
+        fed.ingest(blocks, salt="cats")
+        fed.fit(ForestParams(n_estimators=2, max_depth=2, n_bins=16,
+                             seed=2))
+        totals = TRACER.totals()
+        prep = [s for s in TRACER.spans() if s["name"] == "fit.prepare"]
+        cats = [s for s in TRACER.spans()
+                if s["name"] == "ingest.categories"]
+    finally:
+        TRACER.disable()
+        TRACER.reset()
+    assert totals["ingest.bin"][0] == 2
+    assert len(prep) == 1 and prep[0]["attrs"]["cat_cols"] == int(categorical)
+    if categorical:
+        assert totals["ingest.categories"][0] == 1
+        assert cats[0]["attrs"] == {"party": "a", "rows": n, "columns": 1}
+    else:
+        assert "ingest.categories" not in totals

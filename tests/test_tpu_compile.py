@@ -6,7 +6,8 @@ cannot see what Mosaic refuses — sub-tile reshapes, illegal block tilings —
 so these compiles guard the kernel's layout at the shapes a fit issues:
 F <= 8 and F > 8 per party (all padded columns, or a tree's compacted
 feature subsample), node levels 1 and 256 (``frontier_cap``),
-32 bins, 2 (classification) and 3 (regression) stat channels.
+32 bins (128 for KDD Cup 99's categories), 2 (classification) and 3
+(regression) stat channels.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
@@ -57,4 +58,18 @@ def test_histogram_compiles_for_v5e(one_chip, n, f, n_level, c):
         lambda xb, seg, stats: histogram_pallas(xb, seg, stats, n_level, 32)
     ).lower(sds((n, f), jnp.uint8), sds((n,), jnp.int32),
             sds((n, c), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n_level", [1, 128])
+def test_histogram_compiles_for_v5e_at_128_bins(one_chip, n_level):
+    """The KDD Cup 99 network operator's compacted 6 columns at 128 bins,
+    one bin per category of its 70-category ``service`` column."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda xb, seg, stats: histogram_pallas(xb, seg, stats, n_level, 128)
+    ).lower(sds((494_021, 6), jnp.uint8), sds((494_021,), jnp.int32),
+            sds((494_021, 2), jnp.float32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
