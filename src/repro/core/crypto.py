@@ -24,39 +24,56 @@ import hashlib
 
 import numpy as np
 
+from repro.observability import trace as tracing
+
 DEFAULT_SALT = "repro-ff"
 
-# preimage "salt:id" -> hexdigest.  The serving request path re-hashes every
-# request's sample IDs for alignment; production traffic revisits the same ID
-# universe wave after wave, so the crypto loop is memoized.  Bounded: when
-# full it is cleared wholesale (IDs re-hash on the next request) rather than
-# growing one entry per distinct ID forever.
-_HASH_CACHE: dict[str, str] = {}
+#: A hashed sample ID: the raw 32-byte sha256 digest.  Digests sort in the
+#: order of their lowercase hex spelling, so either gives the same alignment.
+DIGEST = np.dtype("S32")
+
+# preimage "salt:id" -> digest, for party-block serving requests
+# (``submit_parties`` -> ``bin_party_blocks``), which hash their sample IDs to
+# align them; production traffic revisits the same ID universe wave after
+# wave.  Raw-row requests hash nothing, and ingest hashes each ID once per
+# salt, so neither uses it.  Bounded: when full it is cleared wholesale (IDs
+# re-hash on the next request) rather than growing one entry per distinct ID
+# forever.
+_HASH_CACHE: dict[str, bytes] = {}
 _HASH_CACHE_MAX = 1 << 20
 
 
-def hash_ids(ids, salt: str = DEFAULT_SALT) -> np.ndarray:
+def hash_ids(ids, salt: str = DEFAULT_SALT, *,
+             memo: bool = False) -> np.ndarray:
     """Irreversible sample-ID encryption for alignment (paper: MD5).
 
-    Memoized per (salt, id) preimage — repeated serving requests over the
-    same ID universe skip the sha256 loop entirely.  Bit-identical to the
-    uncached digest by construction (the cache stores the digest itself)."""
-    cache, sha256 = _HASH_CACHE, hashlib.sha256
-    if len(cache) > _HASH_CACHE_MAX:
-        cache.clear()
-    out = []
-    for i in ids:
-        key = f"{salt}:{i}"
-        h = cache.get(key)
-        if h is None:
-            h = sha256(key.encode()).hexdigest()
-            cache[key] = h
-        out.append(h)
-    return np.asarray(out)
+    Returns the sha256 digest of ``f"{salt}:{id}"`` for each ID as one
+    ``S32`` array (:data:`DIGEST`): raw fixed-width digests, not hex.
+    ``memo=True`` memoizes per preimage, for a caller that hashes the same
+    IDs request after request; bit-identical either way (the memo stores the
+    digest itself)."""
+    if (isinstance(ids, np.ndarray) and ids.ndim == 1
+            and ids.dtype.kind in "biuOSU"):
+        ids = ids.tolist()  # faster to iterate; formats as NumPy scalars do
+    sha256 = hashlib.sha256
+    if memo:
+        cache = _HASH_CACHE
+        if len(cache) > _HASH_CACHE_MAX:
+            cache.clear()
+        digests = []
+        for i in ids:
+            key = f"{salt}:{i}"
+            d = cache.get(key)
+            if d is None:
+                d = cache[key] = sha256(key.encode()).digest()
+            digests.append(d)
+    else:
+        digests = [sha256(f"{salt}:{i}".encode()).digest() for i in ids]
+    return np.frombuffer(bytearray().join(digests), dtype=DIGEST)
 
 
 def align_ids(*hashed_parties: np.ndarray,
-              check_unique: bool = True) -> tuple[np.ndarray, ...]:
+              names=None) -> tuple[np.ndarray, ...]:
     """Private-set-intersection stand-in, generalized to M parties.
 
     Iterated hashed-ID intersection (paper §4.3: alignment sees hashed IDs
@@ -66,47 +83,89 @@ def align_ids(*hashed_parties: np.ndarray,
     invariant to each party's row order and to the order the parties are
     listed in.
 
+    Hashed IDs are :func:`hash_ids`' ``S32`` digests: the parties are sorted
+    and intersected on the big-endian ``uint64`` of each digest's first 8
+    bytes, and the full digests decide wherever two of those keys are equal
+    within a party or match across parties with different digests; any such
+    case reruns the whole alignment on the full digests, so the result is
+    exact.  Arrays of any other dtype align on their own values.  Runs in
+    the span ``ingest.align``, whose ``prefix_ties`` counts the equal-key
+    runs and false cross-party matches that went to the full digests.
+
     Raises ValueError on duplicate hashed IDs within a party (alignment
-    would be ambiguous) and on an empty intersection (no shared samples).
-    Callers that already validated per-party uniqueness upstream (with
-    better error context, e.g. partyblock.align_party_blocks naming the
-    party) pass ``check_unique=False`` to skip the second O(n log n) sort —
-    the serving request path hits this per request.
+    would be ambiguous), naming the party by ``names`` (default: its
+    index), and on an empty intersection (no shared samples).
     """
     if not hashed_parties:
         raise ValueError("align_ids needs at least one party's hashed IDs")
-    hs = [np.asarray(h).reshape(-1) for h in hashed_parties]
-    if check_unique:
-        for i, h in enumerate(hs):
-            if np.unique(h).size != h.size:
-                raise ValueError(
-                    f"party {i} has duplicate sample IDs: alignment on "
-                    f"hashed IDs is ambiguous — deduplicate before ingest")
-    common = np.sort(hs[0])
-    for h in hs[1:]:
-        common = np.intersect1d(common, h, assume_unique=True)
-    if common.size == 0:
+    hs = [np.ascontiguousarray(h).reshape(-1) for h in hashed_parties]
+    names = list(range(len(hs)) if names is None else names)
+    with tracing.TRACER.span("ingest.align", parties=len(hs)) as span:
+        positions, ties = None, 0
+        if all(h.dtype == DIGEST for h in hs):
+            positions, runs = _align_unique(
+                [h.view(">u8").reshape(-1, 4)[:, 0] for h in hs])
+            ties = sum(runs)
+            if positions is not None:
+                ties = _false_matches(hs, positions)
+                positions = None if ties else positions
+        if positions is None:
+            positions, runs = _align_unique(hs)
+        span.set(prefix_ties=ties)
+    for name, r in zip(names, runs):
+        if r:
+            raise ValueError(
+                f"party {name!r} has duplicate sample IDs: alignment "
+                f"would be ambiguous — deduplicate before ingest")
+    if positions[0].size == 0:
         raise ValueError(
-            f"empty hashed-ID intersection across {len(hs)} parties: the "
-            f"parties share no samples (same salt on every party?)")
-    out = []
+            f"empty hashed-ID intersection across parties {names}: no "
+            f"shared samples to align (same ID space and salt on every "
+            f"party?)")
+    return tuple(p.astype(np.int64, copy=False) for p in positions)
+
+
+def _align_unique(hs):
+    """Sort each party's values once.  Returns ``(positions, runs)``: the
+    positions of the values common to all parties, in sorted order, and
+    per party the count of runs of repeated values; positions is None
+    where any value repeats."""
+    orders, sorted_, runs = [], [], []
     for h in hs:
-        order = np.argsort(h)
-        out.append(order[np.searchsorted(h, common, sorter=order)]
-                   .astype(np.int64))
-    return tuple(out)
+        order = np.argsort(h)   # unstable is exact: a repeat stops below
+        s = h[order]
+        eq = s[1:] == s[:-1]
+        runs.append(int(eq[:1].sum() + np.count_nonzero(eq[1:] & ~eq[:-1])))
+        orders.append(order)
+        sorted_.append(s)
+    if any(runs):
+        return None, runs
+    common = sorted_[0]
+    for s in sorted_[1:]:
+        common = np.intersect1d(common, s, assume_unique=True)
+    return [o[np.searchsorted(s, common)]
+            for o, s in zip(orders, sorted_)], runs
 
 
-def align_hashed(hashes, names, *, check_unique: bool = True,
-                 identity_fast_path: bool = True):
+def _false_matches(hs, positions) -> int:
+    """Rows whose digests differ from party 0's, though their 64-bit
+    prefixes matched."""
+    rows = [np.take(h.view(np.uint64).reshape(-1, 4), p, axis=0)
+            for h, p in zip(hs, positions)]
+    return sum(int(np.count_nonzero((r != rows[0]).any(axis=1)))
+               for r in rows[1:] if not np.array_equal(r, rows[0]))
+
+
+def align_hashed(hashes, names, *, identity_fast_path: bool = True):
     """Align M parties' already-hashed ID arrays with the loud-error contract.
 
     The shared back half of every ingest path (distributed workers,
-    streaming sources): validates per-party uniqueness with the party *name*
-    attached, takes the pre-aligned identity fast path when all arrays are
-    equal (preserving the caller's row order bit-for-bit), and otherwise
-    runs :func:`align_ids` onto the canonical sorted-hash common ordering —
-    rewording the empty-intersection error with the party names.
+    streaming sources): takes the pre-aligned identity fast path when all
+    arrays are equal (preserving the caller's row order bit-for-bit), and
+    otherwise runs :func:`align_ids` onto the canonical sorted-hash common
+    ordering, which rejects duplicates and an empty intersection with the
+    party names attached.  The identity fast path checks no uniqueness: the
+    callers validate their parties' raw IDs before hashing.
 
     Callers that decide the fast path on *raw* IDs themselves (the local
     streaming plane, mirroring align_party_blocks exactly) pass
@@ -117,12 +176,6 @@ def align_hashed(hashes, names, *, check_unique: bool = True,
     party and the common hashed IDs in the aligned order.
     """
     hs = [np.asarray(h).reshape(-1) for h in hashes]
-    if check_unique:
-        for h, name in zip(hs, names):
-            if np.unique(h).size != h.size:
-                raise ValueError(
-                    f"party {name!r} has duplicate sample IDs: alignment "
-                    f"would be ambiguous — deduplicate before ingest")
     first = hs[0]
     if identity_fast_path and all(h.shape == first.shape
                                   and np.array_equal(h, first)
@@ -133,15 +186,7 @@ def align_hashed(hashes, names, *, check_unique: bool = True,
                 f"{list(names)}: no shared samples to align")
         pos = np.arange(len(first), dtype=np.int64)
         return [pos.copy() for _ in hs], first.copy()
-    try:
-        positions = list(align_ids(*hs, check_unique=False))
-    except ValueError as e:
-        if "intersection" not in str(e):
-            raise
-        raise ValueError(
-            f"empty hashed-ID intersection across parties "
-            f"{list(names)}: no shared samples to align "
-            f"(same ID space and salt on every party?)") from e
+    positions = list(align_ids(*hs, names=names))
     return positions, hs[0][positions[0]]
 
 

@@ -165,7 +165,7 @@ class VerticalPartition:
         both serving request paths: tree engines bin these rows
         (:meth:`bin_party_blocks`), the F-LR engine standardizes them."""
         blocks = self._match_blocks(blocks)
-        common, positions = align_party_blocks(blocks, salt=salt)
+        common, positions = align_party_blocks(blocks, salt=salt, memo=True)
         parts = []
         for i, (b, pos) in enumerate(zip(blocks, positions)):
             gid = self.feat_gid[i][self.feat_gid[i] >= 0]

@@ -117,8 +117,9 @@ class PartyBlock:
     def n_features(self) -> int:
         return int(self.x.shape[1])
 
-    def hashed_ids(self, salt: str = crypto.DEFAULT_SALT) -> np.ndarray:
-        return crypto.hash_ids(self.ids, salt=salt)
+    def hashed_ids(self, salt: str = crypto.DEFAULT_SALT, *,
+                   memo: bool = False) -> np.ndarray:
+        return crypto.hash_ids(self.ids, salt=salt, memo=memo)
 
     # ----------------------------------------------------------------- CSV
     @classmethod
@@ -314,27 +315,38 @@ def is_block_sequence(data) -> bool:
 
 
 def align_party_blocks(blocks: list[PartyBlock], *,
-                       salt: str = crypto.DEFAULT_SALT):
+                       salt: str = crypto.DEFAULT_SALT, memo: bool = False):
     """Align M party blocks on their hashed sample IDs.
 
     Returns ``(common_ids, positions)``: the common *raw* IDs in canonical
-    order (sorted by hashed value), and one int64 position array per block
-    such that ``blocks[i].x[positions[i]]`` rows line up across parties.
+    order (sorted by hashed value: the ``S32`` sha256 digests of
+    :func:`crypto.hash_ids`, whose order is that of their hex spelling), and
+    one int64 position array per block such that ``blocks[i].x[positions[i]]``
+    rows line up across parties.  ``memo`` memoizes the hashing, for the
+    serving path whose requests revisit the same IDs; ingest hashes each ID
+    once per salt and leaves it off.
 
     Pre-aligned blocks (every party lists the identical IDs in the identical
     order — the raw-matrix compat adapter) skip the hashing pass: the
     identity alignment is returned directly, preserving the caller's row
-    order bit-for-bit.
+    order bit-for-bit, after a check of the raw IDs' uniqueness.  Otherwise
+    a duplicate raw ID shows as a duplicate digest, and ``crypto.align_ids``
+    rejects it naming the party.
 
     Spans: ``ingest.hash`` per party's hashing, ``ingest.align`` over the
-    uniqueness checks and the intersection.
+    uniqueness check and the intersection.
     """
     first = blocks[0].ids
     if all(b.ids.shape == first.shape and np.array_equal(b.ids, first)
            for b in blocks[1:]):
         with tracing.TRACER.span("ingest.align", parties=len(blocks),
                                  rows=first.size):
-            _check_unique_ids(blocks)
+            for b in blocks:
+                if np.unique(b.ids).size != b.ids.size:
+                    raise ValueError(
+                        f"party {b.name!r} has duplicate sample IDs: "
+                        f"alignment would be ambiguous — deduplicate "
+                        f"before ingest")
             if first.size == 0:     # the fast path must keep the loud-error
                 raise ValueError(   # contract, not fall through to binning
                     f"empty hashed-ID intersection across parties "
@@ -345,25 +357,6 @@ def align_party_blocks(blocks: list[PartyBlock], *,
     hashed = []
     for b in blocks:
         with tracing.TRACER.span("ingest.hash", party=b.name, rows=b.ids.size):
-            hashed.append(b.hashed_ids(salt))
-    with tracing.TRACER.span("ingest.align", parties=len(blocks)):
-        _check_unique_ids(blocks)
-        try:
-            # uniqueness validated just above, with party names attached
-            positions = crypto.align_ids(*hashed, check_unique=False)
-        except ValueError as e:
-            if "intersection" not in str(e):
-                raise
-            raise ValueError(
-                f"empty hashed-ID intersection across parties "
-                f"{[b.name for b in blocks]}: no shared samples to align "
-                f"(same ID space and salt on every party?)") from e
+            hashed.append(b.hashed_ids(salt, memo=memo))
+    positions = crypto.align_ids(*hashed, names=[b.name for b in blocks])
     return blocks[0].ids[positions[0]], list(positions)
-
-
-def _check_unique_ids(blocks) -> None:
-    for b in blocks:
-        if np.unique(b.ids).size != b.ids.size:
-            raise ValueError(
-                f"party {b.name!r} has duplicate sample IDs: alignment "
-                f"would be ambiguous — deduplicate before ingest")

@@ -764,7 +764,7 @@ def distributed_ingest(coord: Coordinator, sources, n_bins: int, *,
     # per-party uniqueness was validated worker-side (hash_block_ids names
     # the party); align_hashed owns the fast path + loud-error contract
     positions, common = crypto.align_hashed(
-        hashes, [names[w] for w in order], check_unique=False)
+        hashes, [names[w] for w in order])
 
     groups, n_features = feature_groups(
         [metas[w].get("feature_ids") for w in order],
@@ -899,7 +899,7 @@ def distributed_streaming_ingest(coord: Coordinator, sources, n_bins: int, *,
     # workers validated per-party ID uniqueness during the scan
     positions, common = crypto.align_hashed(
         [np.asarray(metas[w]["hashes"]) for w in order],
-        [names[w] for w in order], check_unique=False)
+        [names[w] for w in order])
     groups, n_features = feature_groups(
         [metas[w].get("feature_ids") for w in order],
         [int(metas[w]["n_features"]) for w in order])

@@ -293,8 +293,7 @@ def align_streams(streams: list[PartyStream]):
         pos = np.arange(len(first), dtype=np.int64)
         return first.copy(), [pos.copy() for _ in scans]
     positions, _ = crypto.align_hashed(
-        [s.hashes for s in scans], names,
-        check_unique=False, identity_fast_path=False)
+        [s.hashes for s in scans], names, identity_fast_path=False)
     return scans[0].ids[positions[0]], positions
 
 

@@ -15,6 +15,7 @@ The load-bearing claims:
   * serving re-aligns out-of-order / superset per-party request blocks
     before dispatch (ForestServer.serve_parties, RequestQueue.submit_parties).
 """
+import hashlib
 import os
 import subprocess
 import sys
@@ -83,6 +84,91 @@ def test_align_ids_errors():
         crypto.align_ids(a, crypto.hash_ids(["p", "q"]))
     with pytest.raises(ValueError, match="at least one"):
         crypto.align_ids()
+
+
+def _hex_reference_alignment(views, salt):
+    """Positions from argsort of hex digest strings: the alignment as it
+    was specified before digests were kept as bytes."""
+    hexes = [np.array([hashlib.sha256(f"{salt}:{i}".encode()).hexdigest()
+                       for i in v]) for v in views]
+    common = np.sort(hexes[0])
+    for h in hexes[1:]:
+        common = np.intersect1d(common, h, assume_unique=True)
+    return [np.argsort(h)[np.searchsorted(h, common, sorter=np.argsort(h))]
+            for h in hexes]
+
+
+@pytest.mark.parametrize("n_parties", [3, 4])
+def test_align_ids_equals_hexdigest_reference(n_parties):
+    """Shuffled superset views align exactly as a sort of hex digests
+    does: same positions, same canonical order."""
+    rng = np.random.default_rng(n_parties)
+    ids = np.array([f"c{i:07d}" for i in range(3000)])
+    views = [rng.permutation(np.concatenate([
+        ids, [f"p{k}-{j}" for j in range(50 + 20 * k)]]))
+        for k in range(n_parties)]
+    pos = crypto.align_ids(*[crypto.hash_ids(v, "job") for v in views])
+    ref = _hex_reference_alignment(views, "job")
+    for p, r in zip(pos, ref):
+        assert p.dtype == np.int64
+        np.testing.assert_array_equal(p, r)
+
+
+@pytest.mark.parametrize("ids", [["u1", "u2", "ü3"], np.arange(5),
+                                 np.array(["a", "bb"]), []])
+def test_hash_ids_returns_raw_sha256_digests(ids):
+    got = crypto.hash_ids(ids, salt="s")
+    want = np.array([hashlib.sha256(f"s:{i}".encode()).digest()
+                     for i in ids], dtype="S32")
+    assert got.dtype == crypto.DIGEST and got.shape == (len(ids),)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(crypto.hash_ids(ids, "s", memo=True), want)
+
+
+def _digests(*parts: bytes) -> np.ndarray:
+    assert all(len(p) == 32 for p in parts)
+    return np.array(parts, dtype="S32")
+
+
+_P, _Q = b"\x5a" * 8, b"\xa5" * 8            # shared 8-byte prefixes
+_TIE_LO, _TIE_HI = _P + b"\x01" * 24, _P + b"\x02" * 24
+_FALSE_A, _FALSE_B = _Q + b"\x03" * 24, _Q + b"\x04" * 24
+
+
+def test_align_ids_resolves_planted_prefix_ties():
+    """Digests that share their first 8 bytes, within a party and across
+    parties, align on the full digests: the tied pair in full-digest order,
+    the false cross-party match left out."""
+    shared = [bytes([k]) * 32 for k in (9, 200, 77)]
+    a = _digests(_TIE_HI, shared[0], _FALSE_A, _TIE_LO, shared[1], shared[2])
+    b = _digests(shared[2], _TIE_LO, shared[1], _FALSE_B, shared[0], _TIE_HI)
+    pa, pb = crypto.align_ids(a, b)
+    want = sorted(shared + [_TIE_LO, _TIE_HI])
+    np.testing.assert_array_equal(a[pa], _digests(*want))
+    np.testing.assert_array_equal(b[pb], _digests(*want))
+    # a false cross-party match alone (no tie within a party)
+    pa, pb = crypto.align_ids(_digests(_FALSE_A, shared[0]),
+                              _digests(shared[0], _FALSE_B))
+    assert pa.tolist() == [1] and pb.tolist() == [0]
+    with pytest.raises(ValueError, match="intersection"):
+        crypto.align_ids(_digests(_FALSE_A), _digests(_FALSE_B))
+
+
+def test_duplicate_raw_ids_raise_naming_the_party():
+    """A duplicate raw ID is a duplicate digest; the error names the party,
+    on the hashed path (shuffled blocks) and the identity fast path."""
+    ids = np.array([f"u{i}" for i in range(6)])
+    dup = np.concatenate([ids[:5], ids[:1]])
+    x = np.zeros((6, 2))
+    for other_ids in (ids[::-1], dup):          # hashed path, fast path
+        blocks = [PartyBlock("bank", x, ids=dup),
+                  PartyBlock("shop", x, ids=other_ids)]
+        with pytest.raises(ValueError,
+                           match="party 'bank' has duplicate sample IDs"):
+            align_party_blocks(blocks)
+    with pytest.raises(ValueError, match="party 'shop' has duplicate"):
+        crypto.align_ids(crypto.hash_ids(ids), crypto.hash_ids(dup),
+                         names=["bank", "shop"])
 
 
 def test_ingest_errors_are_loud():
@@ -390,17 +476,29 @@ def test_linear_serve_parties_roundtrip():
 
 
 def test_hash_ids_cache_bit_identity():
-    """The serving-path hash cache is invisible: cold and warm lookups
-    produce identical digests, and repeated IDs hit the cache."""
+    """The serving-path hash memo is invisible: cold and warm lookups
+    produce identical digests, repeated IDs hit the memo, and ingest
+    (which hashes each ID once per salt) leaves it untouched."""
     crypto._HASH_CACHE.clear()
     ids = np.array([f"u{i}" for i in range(50)])
-    cold = crypto.hash_ids(ids)
+    cold = crypto.hash_ids(ids, memo=True)
+    assert cold.dtype == crypto.DIGEST
     assert len(crypto._HASH_CACHE) >= 50
-    warm = crypto.hash_ids(np.concatenate([ids, ids]))
+    warm = crypto.hash_ids(np.concatenate([ids, ids]), memo=True)
     np.testing.assert_array_equal(warm[:50], cold)
     np.testing.assert_array_equal(warm[50:], cold)
+    np.testing.assert_array_equal(crypto.hash_ids(ids), cold)
     # a different salt is a different preimage, never a stale cache hit
-    assert not np.array_equal(crypto.hash_ids(ids, salt="other"), cold)
+    assert not np.array_equal(crypto.hash_ids(ids, salt="other", memo=True),
+                              cold)
+    crypto._HASH_CACHE.clear()
+    x, y = make_classification(60, 4, seed=3)
+    blocks, _, _ = make_party_views(x, y, 2, overlap=0.8, seed=3)
+    part = Federation(parties=2, n_bins=8).ingest(blocks, salt="job")
+    assert crypto._HASH_CACHE == {}
+    part.bin_party_blocks(blocks, salt="job")     # a serving request
+    assert len(crypto._HASH_CACHE) == np.unique(
+        np.concatenate([b.ids for b in blocks])).size
 
 
 def test_serve_parties_validates_block_names():

@@ -17,7 +17,10 @@ The load-bearing claims:
   * ``fit.prepare`` reports the columns each party's split search
     histograms against the party's padded width, and its categorical
     columns; ``ingest.categories`` times each party's category
-    dictionaries apart from its quantile binning.
+    dictionaries apart from its quantile binning;
+  * ``ingest.align`` counts in ``prefix_ties`` the 64-bit digest prefixes
+    that tied within a party or matched falsely across parties (0 on real
+    ingest, the planted number on planted digests).
 """
 import contextlib
 import glob
@@ -28,7 +31,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import ForestParams, PartyBlock
+from repro.core import ForestParams, PartyBlock, crypto
 from repro.data import make_classification, make_party_views
 from repro.federation import Federation
 from repro.observability import TRACER, Tracer
@@ -225,14 +228,14 @@ def pipeline_runs(tmp_path_factory):
     try:
         with profiler_session(log_dir):
             traced = _pipeline()
-        totals = TRACER.totals()
+        totals, spans = TRACER.totals(), TRACER.spans()
     finally:
         TRACER.reset()
-    return plain, traced, totals, host_events(log_dir)
+    return plain, traced, totals, host_events(log_dir), spans
 
 
 def test_pipeline_under_session_records_every_span(pipeline_runs):
-    _, (_, _, waves), totals, events = pipeline_runs
+    _, (_, _, waves), totals, events, _ = pipeline_runs
     missing = [name for name in PIPELINE_SPANS if not totals.get(name)]
     assert not missing
     assert totals["serve.dispatch"][0] == totals["serve.collect"][0] == waves
@@ -251,7 +254,7 @@ def test_pipeline_under_session_records_every_span(pipeline_runs):
 
 
 def test_session_leaves_trees_and_answers_bit_identical(pipeline_runs):
-    (trees_a, answers_a, waves_a), (trees_b, answers_b, waves_b), _, _ = \
+    (trees_a, answers_a, waves_a), (trees_b, answers_b, waves_b), _, _, _ = \
         pipeline_runs
     for la, lb in zip(jax.tree.leaves(trees_a), jax.tree.leaves(trees_b)):
         np.testing.assert_array_equal(la, lb)
@@ -260,6 +263,38 @@ def test_session_leaves_trees_and_answers_bit_identical(pipeline_runs):
     for a, b in zip(answers_a, answers_b):
         assert a.dtype == b.dtype
         np.testing.assert_array_equal(a, b)
+
+
+def test_ingest_spans_count_rows_and_prefix_ties(pipeline_runs):
+    """``ingest.hash`` carries each party's rows; ``ingest.align`` carries
+    ``prefix_ties``, 0 where no two 64-bit digest prefixes meet."""
+    *_, spans = pipeline_runs
+    hashed = [s["attrs"] for s in spans if s["name"] == "ingest.hash"]
+    align = [s["attrs"] for s in spans if s["name"] == "ingest.align"]
+    assert len(hashed) == 2 and all(a["rows"] > 0 for a in hashed)
+    assert [a["prefix_ties"] for a in align] == [0]
+
+
+@pytest.mark.parametrize("a,b,ties", [
+    # one equal-prefix run in each party
+    ([b"P" * 8 + b"\x02" * 24, b"P" * 8 + b"\x01" * 24, b"s" * 32],
+     [b"s" * 32, b"P" * 8 + b"\x01" * 24, b"P" * 8 + b"\x02" * 24], 2),
+    # one false cross-party match
+    ([b"Q" * 8 + b"\x03" * 24, b"s" * 32],
+     [b"s" * 32, b"Q" * 8 + b"\x04" * 24], 1),
+    ([b"s" * 32, b"t" * 32], [b"t" * 32], 0),
+])
+def test_align_ids_reports_planted_prefix_ties(a, b, ties):
+    TRACER.reset()
+    TRACER.enable()
+    try:
+        crypto.align_ids(np.array(a, "S32"), np.array(b, "S32"))
+        align = [s["attrs"] for s in TRACER.spans()
+                 if s["name"] == "ingest.align"]
+    finally:
+        TRACER.disable()
+        TRACER.reset()
+    assert align == [{"parties": 2, "prefix_ties": ties}]
 
 
 def test_queue_wait_counted_once_after_rollback(monkeypatch):
